@@ -1295,28 +1295,22 @@ def _provenance() -> dict:
     """What produced this artifact: BENCH_PR*.json files are compared
     across machines and months, so every artifact records the software
     stack, the accelerator, the REPRO_* env knobs that change kernel
-    behavior, and the exact source revision. Every field degrades to
-    None rather than failing the dump."""
+    behavior, and the exact source revision. The device fields come from
+    JAX and a failed probe fails the dump; only the git revision may be
+    None (a checkout without git)."""
     import os
     import subprocess
 
-    prov: dict = {"jax": None, "jaxlib": None, "device_kind": None,
-                  "device_count": None, "git_sha": None,
+    import jax
+    import jaxlib
+
+    devs = jax.devices()
+    prov: dict = {"jax": jax.__version__, "jaxlib": jaxlib.__version__,
+                  "platform": devs[0].platform,
+                  "device_kind": devs[0].device_kind,
+                  "device_count": len(devs), "git_sha": None,
                   "env": {k: v for k, v in sorted(os.environ.items())
                           if k.startswith("REPRO_")}}
-    try:
-        import jax
-        prov["jax"] = jax.__version__
-        try:
-            import jaxlib
-            prov["jaxlib"] = jaxlib.__version__
-        except Exception:
-            pass
-        devs = jax.devices()
-        prov["device_kind"] = devs[0].device_kind if devs else None
-        prov["device_count"] = len(devs)
-    except Exception:
-        pass
     try:
         prov["git_sha"] = subprocess.run(
             ["git", "rev-parse", "HEAD"], capture_output=True, text=True,
@@ -1359,7 +1353,9 @@ def _dump_json(path: str) -> None:
     print(f"registry snapshot written to {mpath}")
 
 
-if __name__ == "__main__":
+def main():
+    from repro.launch import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--roofline", action="store_true")
     ap.add_argument("--quality", action="store_true")
@@ -1404,3 +1400,7 @@ if __name__ == "__main__":
         faults_mode(smoke=args.smoke)
     if args.json:
         _dump_json(args.json)
+
+
+if __name__ == "__main__":
+    main()

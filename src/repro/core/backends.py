@@ -40,6 +40,7 @@ Registering a new backend::
 from __future__ import annotations
 
 import importlib
+import logging
 from collections import OrderedDict
 from typing import Callable, Dict, Optional, Type
 
@@ -50,6 +51,7 @@ import numpy as np
 from repro.core import query as query_mod
 from repro.core.types import DeltaCorrection, QueryResult, RankTable, \
     RankTableConfig, StoredUsers, take_user_rows
+from repro.obs import registry as obs
 from repro.obs import trace
 
 
@@ -318,7 +320,16 @@ class ShardedBackend(QueryBackend):
             # opaque divisibility error (and a maintenance-loop rebuild
             # would then fail on every retry). Fall back to the dense
             # build — the resulting table queries fine on this backend as
-            # long as n itself stays shard-divisible.
+            # long as n itself stays shard-divisible — and say so: the
+            # counter lets a deployment check its index really is sharded
+            obs.get_default().counter(
+                "sharded_single_device_builds_total",
+                "sharded-backend builds that ran on one device because n "
+                "or m does not divide the mesh size").inc()
+            logging.getLogger(__name__).warning(
+                "sharded build of n=%d, m=%d runs on one device: both must "
+                "be multiples of the %d shards", users.shape[0],
+                items.shape[0], nshards)
             return super().build_index(users, items, cfg, key)
         return D.build_sharded(users, items, cfg, key, self.mesh)
 

@@ -32,15 +32,9 @@ from repro.core import rank_table as rt_mod
 from repro.core.query import lemma1_key, lemma1_select, \
     lookup_bounds_batch, user_scores_batch
 from repro.core.types import DeltaCorrection, QueryResult, RankTable, \
-    RankTableConfig, StoredUsers, kth_smallest, take_user_rows
+    RankTableConfig, StoredUsers, kth_smallest, matmul, take_user_rows
 
 AXIS = "shard"
-
-# jax.shard_map graduated from jax.experimental after 0.4.x; support both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-else:                                        # pragma: no cover - version dep
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 
 def flat_mesh(mesh_or_devices) -> Mesh:
@@ -113,7 +107,7 @@ def build_sharded(users: jax.Array, items: jax.Array, cfg: RankTableConfig,
             "build_rank_table for the exact-threshold oracle mode")
     m = items.shape[0]
 
-    norms_local = _shard_map(
+    norms_local = jax.shard_map(
         lambda it: jnp.linalg.norm(it.astype(jnp.float32), axis=1),
         mesh=mesh, in_specs=P(AXIS, None), out_specs=P(AXIS))
     norms = norms_local(items)
@@ -124,7 +118,7 @@ def build_sharded(users: jax.Array, items: jax.Array, cfg: RankTableConfig,
     max_norm = norms[order[0]]
 
     def local_build(u_shard, smp, w, mx):
-        scores = (u_shard @ smp.T).astype(jnp.float32)
+        scores = matmul(u_shard, smp.T).astype(jnp.float32)
         if cfg.threshold_mode == "norm_bound":
             bound = jnp.linalg.norm(u_shard.astype(jnp.float32),
                                     axis=1) * mx
@@ -147,7 +141,7 @@ def build_sharded(users: jax.Array, items: jax.Array, cfg: RankTableConfig,
 
     n_out = 2 + len(RankTable._QUANT_FIELDS) \
         if cfg.storage.kind == "int8" else 2
-    out = _shard_map(
+    out = jax.shard_map(
         local_build, mesh=mesh,
         in_specs=(P(AXIS, None), P(None, None), P(None), P()),
         out_specs=tuple([P(AXIS, None)] * n_out))(
@@ -235,7 +229,7 @@ def make_batch_query_fn(mesh: Mesh, k: int, n: int, c: float, *,
         # lowers to exactly the pre-spec program (bit-identity)
         delta = (corr,) if with_delta else ()
         delta_specs = (_corr_specs(corr),) if with_delta else ()
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local_part, mesh=mesh,
             in_specs=(_rt_specs(rt), _user_specs(users),
                       P(None, None)) + delta_specs,
@@ -354,7 +348,7 @@ def make_pruned_batch_query_fn(mesh: Mesh, k: int, n: int, c: float, *,
                        corr: DeltaCorrection = None) -> QueryResult:
         delta = (corr,) if with_delta else ()
         delta_specs = (_corr_specs(corr),) if with_delta else ()
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             local_part, mesh=mesh,
             in_specs=(_rt_specs(rt), _user_specs(users),
                       P(None, None), P(AXIS, None), P(AXIS, None),
@@ -412,11 +406,11 @@ def ring_exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array,
     perm = [(i, (i + 1) % nshards) for i in range(nshards)]
 
     def local(u_shard, it_shard, qv):
-        uq = (u_shard @ qv).astype(jnp.float32)
+        uq = matmul(u_shard, qv).astype(jnp.float32)
 
         def body(_, carry):
             counts, blk = carry
-            scores = (u_shard @ blk.T).astype(jnp.float32)
+            scores = matmul(u_shard, blk.T).astype(jnp.float32)
             counts = counts + jnp.sum(scores > uq[:, None], axis=1)
             blk = jax.lax.ppermute(blk, AXIS, perm)
             return counts, blk
@@ -425,7 +419,7 @@ def ring_exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array,
             0, nshards, body, (jnp.zeros_like(uq), it_shard))
         return 1.0 + counts
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None), P()),
         out_specs=P(AXIS))(users, items, q)

@@ -70,10 +70,9 @@ silently reinterpreted.
 The tile size is the `REPRO_ELASTIC_TILE` env knob (default 256, must be
 a multiple of 32 so one tile satisfies every TPU min-tile: f32 (8, 128),
 bf16 (16, 128), int8 (32, 128)). On CPU the fused inner runs the Pallas
-tile in interpret mode (`REPRO_INTERPRET`, see `kernels.ops`); interpret
-kernels trace into the fori_loop body like any jnp code, so the
-compile-once property holds in both modes and TPU validation needs no
-source edit.
+tile in interpret mode (`repro.kernels.interpret_mode`); interpret kernels
+trace into the fori_loop body like any jnp code, so the compile-once
+property holds in both modes.
 
 `compiled_program_count()` is the serving-side observability hook: a
 monotone count of compiled programs across the query stack's jit entry
@@ -118,8 +117,8 @@ def default_tile() -> int:
 
     Must be a multiple of 32 (one tile then satisfies the TPU min-tile
     of every storage dtype — f32 (8, 128), bf16 (16, 128), int8
-    (32, 128) — so the same knob value validates on hardware with
-    REPRO_INTERPRET=0)."""
+    (32, 128)). It is also the fused kernel's block_n, which
+    tests/test_tpu_compile.py compiles for a v5e."""
     raw = os.environ.get("REPRO_ELASTIC_TILE", "").strip()
     tile = int(raw) if raw else 256
     if tile < 32 or tile % 32:
@@ -311,25 +310,21 @@ def _elastic_query_impl(rt: RankTable, users, qs: jax.Array,
 _elastic_query = jax.jit(_elastic_query_impl, static_argnames=_STATIC_ARGS)
 
 
-def _serve_donate_args() -> tuple:
-    """Buffer donation for the SERVING entry: the scheduler's per-tick
-    query block is staged into a fresh device buffer each tick and never
-    read after dispatch, so on accelerators XLA may reuse its memory for
-    outputs. On CPU donation is a no-op that warns per call — alias the
-    plain entry instead (same jit object: zero extra compiles)."""
-    try:
-        if jax.default_backend() in ("gpu", "cuda", "rocm", "tpu"):
-            return ("qs",)
-    except Exception:  # pragma: no cover - backend probe must never fail
-        pass
-    return ()
+# The SERVING entry donates the query block: the scheduler stages each
+# tick's block into a fresh device buffer that is never read after
+# dispatch, so on an accelerator XLA may reuse its memory for outputs.
+_elastic_query_serve = jax.jit(_elastic_query_impl,
+                               static_argnames=_STATIC_ARGS,
+                               donate_argnames=("qs",))
 
 
-_SERVE_DONATE = _serve_donate_args()
-_elastic_query_serve = (
-    jax.jit(_elastic_query_impl, static_argnames=_STATIC_ARGS,
-            donate_argnames=_SERVE_DONATE)
-    if _SERVE_DONATE else _elastic_query)
+def _serve_program():
+    """The jit entry a serving call dispatches to, chosen at the call:
+    on the CPU donation is a no-op that warns per call, so the plain
+    entry serves there (same computation, zero extra compiles)."""
+    if jax.default_backend() == "cpu":
+        return _elastic_query
+    return _elastic_query_serve
 
 
 # -------------------------------------------------------- observability
@@ -539,7 +534,7 @@ class ElasticBackend(BK.QueryBackend):
                 return self.inner.dispatch_device(rt, users, qs, k=k, c=c)
             return self.inner.dispatch_device(rt, users, qs, k=k, c=c,
                                               delta=delta)
-        return self._query_via(_elastic_query_serve, rt, users, qs,
+        return self._query_via(_serve_program(), rt, users, qs,
                                k=k, c=c, delta=delta)
 
 

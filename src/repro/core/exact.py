@@ -12,6 +12,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.types import matmul
+
 
 def exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array,
                 block: int = 4096) -> jax.Array:
@@ -32,8 +34,8 @@ def exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array,
     upad = jnp.pad(users, ((0, pad), (0, 0)))
 
     def body(_, ublk):
-        uq = ublk @ q                                   # (block,)
-        up = ublk @ items.T                             # (block, m)
+        uq = matmul(ublk, q)                            # (block,)
+        up = matmul(ublk, items.T)                      # (block, m)
         r = 1 + jnp.sum(up > uq[:, None], axis=1)
         return None, r.astype(jnp.int32)
 
@@ -59,4 +61,4 @@ def reverse_k_ranks(users: jax.Array, items: jax.Array, q: jax.Array,
 
 def exact_rank_single(u: jax.Array, items: jax.Array, q: jax.Array) -> jax.Array:
     """r(q, u, P) for one user — the literal Definition 1."""
-    return 1 + jnp.sum((items @ u) > jnp.dot(u, q)).astype(jnp.int32)
+    return 1 + jnp.sum(matmul(items, u) > matmul(u, q)).astype(jnp.int32)

@@ -126,7 +126,8 @@ from repro.core import rank_table as rt_mod
 from repro.core.query import _bucketize, lemma1_select, \
     lookup_bounds_batch, user_scores_batch
 from repro.core.types import DeltaCorrection, EPS_BF16, QueryResult, \
-    RankTable, StoredUsers, _I8_TRANSFORM_PAD, kth_smallest, take_user_rows
+    RankTable, StoredUsers, _I8_TRANSFORM_PAD, kth_smallest, matmul, \
+    take_user_rows
 
 # Summary block size. MUST match the fused kernel's user-tile block_n so a
 # kept block is exactly one kernel grid step (and the per-tile matmul is
@@ -450,10 +451,12 @@ def _envelope_bounds(summary: BlockSummary, qs: jax.Array
     d = qs.shape[1]
     qp = jnp.maximum(qs, 0.0).astype(jnp.float32)          # (B, d)
     qn = jnp.minimum(qs, 0.0).astype(jnp.float32)
-    s_hi = summary.dim_max @ qp.T + summary.dim_min @ qn.T  # (nb, B)
-    s_lo = summary.dim_min @ qp.T + summary.dim_max @ qn.T
+    s_hi = (matmul(summary.dim_max, qp.T)
+            + matmul(summary.dim_min, qn.T))               # (nb, B)
+    s_lo = matmul(summary.dim_min, qp.T) + matmul(summary.dim_max, qn.T)
     absmax = jnp.maximum(jnp.abs(summary.dim_min), jnp.abs(summary.dim_max))
-    slack = (_SCORE_SLACK * d) * (absmax @ jnp.abs(qs).T) + _SCORE_SLACK_ABS
+    slack = ((_SCORE_SLACK * d) * matmul(absmax, jnp.abs(qs).T)
+             + _SCORE_SLACK_ABS)
     s_hi = s_hi + slack
     s_lo = s_lo - slack
     if summary.norm_min is not None:
@@ -465,7 +468,7 @@ def _envelope_bounds(summary: BlockSummary, qs: jax.Array
         q32 = qs.astype(jnp.float32)
         q_norm = jnp.sqrt(jnp.sum(q32 * q32, axis=1))       # (B,)
         q_hat = q32 / jnp.maximum(q_norm, 1e-30)[:, None]
-        cos_t = summary.mu @ q_hat.T                        # (nb, B)
+        cos_t = matmul(summary.mu, q_hat.T)                 # (nb, B)
         cos_r = summary.cos_r                               # (nb, 1)
         sin_r = jnp.sqrt(jnp.maximum(1.0 - cos_r * cos_r, 0.0))
         ct_hi = jnp.clip(cos_t + cs, -1.0, 1.0)     # θ rounded down

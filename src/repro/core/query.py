@@ -34,7 +34,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import DeltaCorrection, EPS_BF16, QueryResult, \
-    RankTable, StoredUsers, _I8_TRANSFORM_PAD, kth_smallest
+    RankTable, StoredUsers, _I8_TRANSFORM_PAD, kth_smallest, matmul, \
+    round_bf16
 
 # §Perf H4b (REFUTED): a gather-based bisection was hypothesized to touch
 # only ~log2(τ)·n elements instead of streaming the full (n, τ) rows.
@@ -87,7 +88,7 @@ def _dequant_matmul(rows: jax.Array, scale: Optional[jax.Array],
 
     def block(args):
         rb, sb = args
-        out = rb.astype(jnp.float32) @ qt
+        out = matmul(rb.astype(jnp.float32), qt)
         return out if sb is None else out * sb
 
     nb = n // _DEQUANT_MM_BLOCK
@@ -118,7 +119,7 @@ def user_scores_batch(users, qs: jax.Array
     dequant-aware lookup folds into the (r↓, r↑) widening.
     """
     if not isinstance(users, StoredUsers):
-        return (users @ qs.T).astype(jnp.float32), None
+        return matmul(users, qs.T).astype(jnp.float32), None
     scores = _dequant_matmul(users.rows, users.scale, qs)   # (n, B)
     slack = users.row_slack * jnp.sum(jnp.abs(qs), axis=1)[None, :]
     return scores, slack
@@ -178,8 +179,11 @@ def _lookup_bounds_bf16(rt: RankTable, uq: jax.Array,
     thr, tab = rt.thresholds, rt.table
     s_hi = uq if slack is None else uq + slack
     s_lo = uq if slack is None else uq - slack
-    idx_hi = _searchsorted_rows(thr, s_hi.astype(thr.dtype), "right")
-    idx_lo = _searchsorted_rows(thr, s_lo.astype(thr.dtype), "left")
+    # round_bf16 first: a bare cast may be dropped as excess precision
+    idx_hi = _searchsorted_rows(thr, round_bf16(s_hi).astype(thr.dtype),
+                                "right")
+    idx_lo = _searchsorted_rows(thr, round_bf16(s_lo).astype(thr.dtype),
+                                "left")
     m_plus_1 = (rt.m + 1).astype(jnp.float32)
     up_col = jnp.clip(idx_lo - 1, 0, tau - 1)
     lo_col = jnp.clip(idx_hi, 0, tau - 1)

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from typing import NamedTuple, Optional
 
 from repro.core.types import DeltaCorrection, RankTable, RankTableConfig, \
-    partition_sizes
+    matmul, partition_sizes
 
 
 def stratified_sample_indices(key: jax.Array, m: int, cfg: RankTableConfig
@@ -97,7 +97,7 @@ def _threshold_range(users: jax.Array, items_sorted: jax.Array,
                      ) -> tuple[jax.Array, jax.Array]:
     """f_min / f_max per user, per cfg.threshold_mode (§4.2 step 2 + fn. 1)."""
     if cfg.threshold_mode == "exact":
-        full = users @ items_sorted.T                       # O(nmd): tests only
+        full = matmul(users, items_sorted.T)          # O(nmd): tests only
         return full.min(axis=1), full.max(axis=1)
     if cfg.threshold_mode == "norm_bound":
         bound = jnp.linalg.norm(users, axis=1) * jnp.linalg.norm(
@@ -116,7 +116,7 @@ def build_rank_table_sorted(users: jax.Array, items_sorted: jax.Array,
     m = items_sorted.shape[0]
     positions, weights = stratified_sample_indices(key, m, cfg)
     samples = items_sorted[positions]                       # (ω·s, d)
-    scores = (users @ samples.T).astype(jnp.float32)        # (n, ω·s) — MXU
+    scores = matmul(users, samples.T).astype(jnp.float32)   # (n, ω·s) — MXU
     smin, smax = _threshold_range(users, items_sorted, scores, cfg)
     thresholds = threshold_grid(smin, smax, cfg.tau)
     table = estimate_table_rows(scores, weights, thresholds)
@@ -204,9 +204,9 @@ def recompute_user_rows(user_rows: jax.Array, samples: jax.Array,
     threshold_mode="norm_bound". Returns float32 (thresholds, table) rows;
     the caller casts to the table's storage dtype.
     """
-    scores = (user_rows @ samples.T).astype(jnp.float32)    # (t, ω·s)
+    scores = matmul(user_rows, samples.T).astype(jnp.float32)  # (t, ω·s)
     if cfg.threshold_mode == "exact":
-        full = user_rows @ items.T
+        full = matmul(user_rows, items.T)
         smin, smax = full.min(axis=1), full.max(axis=1)
     elif cfg.threshold_mode == "norm_bound":
         bound = jnp.linalg.norm(user_rows.astype(jnp.float32),

@@ -51,12 +51,41 @@ import jax
 import jax.numpy as jnp
 
 
+def dot_precision():
+    """Precision of every engine matmul: full f32 (HIGHEST) off the CPU.
+
+    The certified bounds budget f32 rounding of each score (about d·2⁻²⁴
+    of it); a TPU's default precision rounds f32 operands to bf16, which
+    they do not cover. On the CPU the default precision is f32 already,
+    and keeping it keeps the CPU programs bit for bit. Reads JAX's
+    default backend when the caller is traced."""
+    if jax.default_backend() == "cpu":
+        return None
+    return jax.lax.Precision.HIGHEST
+
+
+def matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """`a @ b` at `dot_precision()`."""
+    return jnp.matmul(a, b, precision=dot_precision())
+
+
 # ---------------------------------------------------------------- storage
 # bf16 keeps 8 mantissa bits; a round-to-nearest cast is within half an
 # ulp, i.e. ~2^-9 relative. 2^-7 over-covers it (including the /(1-eps)
 # reciprocal terms), trading a hair of bound tightness for an airtight
 # widening at every magnitude.
 EPS_BF16 = 2.0 ** -7
+
+
+def round_bf16(x: jax.Array) -> jax.Array:
+    """The bf16 value nearest to each f32 of `x` (ties to even), kept in
+    f32. The bf16 certification needs the score interval's ends rounded
+    exactly so, but XLA on a TPU may drop an f32 → bf16 → f32 round trip
+    as excess precision; integer ops it cannot drop, and Mosaic lowers
+    them in a kernel too."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    b = b + (0x7FFF + ((b >> 16) & 1))
+    return jax.lax.bitcast_convert_type(b & -0x10000, jnp.float32)
 
 # int8 quantized codes live in [-127, 127]; -128 is reserved as the
 # "absent" sentinel (delta-score padding) so a clipped integer compare

@@ -41,7 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import rank_table as rt_mod
-from repro.core.types import DeltaCorrection, RankTableConfig, StorageSpec
+from repro.core.types import DeltaCorrection, RankTableConfig, StorageSpec, \
+    matmul
 
 
 @dataclasses.dataclass(frozen=True)
@@ -266,7 +267,7 @@ def _packed_scores(users: jax.Array, items: jax.Array, width: int,
     spec space, left-pad to the power-of-two bucket with the absent
     sentinel (−inf; −128 for int8 — `rank_table._count_above_range`
     guarantees the sentinel is never counted)."""
-    raw = jnp.sort((users @ items.T).astype(jnp.float32), axis=1)
+    raw = jnp.sort(matmul(users, items.T).astype(jnp.float32), axis=1)
     return spec.pack_scores(raw, _bucket(width) - width)
 
 

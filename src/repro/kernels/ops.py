@@ -2,34 +2,22 @@
 
 These are the entry points the engine uses; each pads inputs to kernel
 tile multiples, invokes the raw pallas_call, and undoes the padding.
-`interpret=True` everywhere in this container (CPU); on TPU the same
-code path runs compiled by flipping `repro.kernels.INTERPRET`.
+Kernels run compiled on an accelerator and interpreted on the CPU
+(`repro.kernels.interpret_mode`); the same code path serves both.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
 
-from repro.core.types import QueryResult, RankTable, StoredUsers
+from repro.core.types import QueryResult, RankTable, StoredUsers, \
+    dot_precision
 from repro.kernels import exact_rank as _er
 from repro.kernels import table_build as _tb
 from repro.kernels import user_scores as _us
 
-
-def _interpret_default() -> bool:
-    """interpret=True executes the kernel bodies in Python on CPU for
-    validation; on a real TPU set REPRO_INTERPRET=0 to run them compiled
-    (the ROADMAP "TPU validation" procedure — no source edit needed)."""
-    return os.environ.get("REPRO_INTERPRET", "1").strip().lower() not in (
-        "0", "false", "no", "off")
-
-
-# Flipped to False on real TPU backends — via the REPRO_INTERPRET env var
-# at import time, or by assigning repro.kernels.ops.INTERPRET directly.
-INTERPRET = _interpret_default()
 
 _LANE = 128     # TPU lane width: pad τ and other minor dims to multiples.
 
@@ -54,17 +42,11 @@ def _pad_cols_edge(x: jax.Array, mult: int) -> jax.Array:
 def bound_ranks(users: jax.Array, q: jax.Array, thresholds: jax.Array,
                 table: jax.Array, *, m: int, block_n: int = 256
                 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Fused u·q + rank-table lookup for all users → (r↓, r↑, est)."""
-    n, tau = thresholds.shape[0], thresholds.shape[1]
-    up = _pad_rows(users.astype(jnp.float32), block_n)
-    # Padded user rows read padded threshold rows; edge-padding keeps them
-    # ascending so the kernel math stays well-defined (results sliced off).
-    tp = _pad_cols_edge(_pad_rows(thresholds, block_n, value=0.0), _LANE)
-    bp = _pad_cols_edge(_pad_rows(table, block_n, value=1.0), _LANE)
-    r_lo, r_up, est = _us.bound_ranks_kernel_call(
-        up, q.astype(jnp.float32), tp, bp, m=m, tau_valid=tau,
-        block_n=block_n, interpret=INTERPRET)
-    return r_lo[:n], r_up[:n], est[:n]
+    """Fused u·q + rank-table lookup for all users → (r↓, r↑, est), each
+    (n,): the B = 1 case of `bound_ranks_batched`."""
+    r_lo, r_up, est = bound_ranks_batched(users, q[None, :], thresholds,
+                                          table, m=m, block_n=block_n)
+    return r_lo[0], r_up[0], est[0]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_n"))
@@ -90,8 +72,7 @@ def bound_ranks_batched(users: jax.Array, qs: jax.Array,
     # sliced off below.
     qt = _pad_rows(qs.astype(jnp.float32), 8).T             # (d, Bp)
     r_lo, r_up, est = _us.bound_ranks_batched_kernel_call(
-        up, qt, tp, bp, m=m, tau_valid=tau, block_n=block_n,
-        interpret=INTERPRET)
+        up, qt, tp, bp, m=m, tau_valid=tau, block_n=block_n)
     return r_lo[:n, :B].T, r_up[:n, :B].T, est[:n, :B].T
 
 
@@ -121,7 +102,7 @@ def bound_ranks_batched_pruned(users: jax.Array, qs: jax.Array,
     B = qs.shape[0]
     r_lo, r_up, est = _us.bound_ranks_batched_masked_kernel_call(
         up, qt, tp, bp, block_ids.astype(jnp.int32), m=m, tau_valid=tau,
-        block_n=block_n, interpret=INTERPRET)
+        block_n=block_n)
     return r_lo[:, :B].T, r_up[:, :B].T, est[:, :B].T
 
 
@@ -129,16 +110,18 @@ def bound_ranks_batched_pruned(users: jax.Array, qs: jax.Array,
 def build_table_rows(users: jax.Array, samples: jax.Array,
                      weights: jax.Array, thresholds: jax.Array, *,
                      block_n: int = 128) -> jax.Array:
-    """Eq. (1) table rows for all users (fused matmul + weighted counts)."""
-    n, tau = thresholds.shape
+    """Eq. (1) table rows for all users (fused matmul + weighted counts).
+
+    The kernel works transposed (users on lanes), so block_n is a lane
+    multiple and the thresholds go in as (τ, n)."""
+    n = thresholds.shape[0]
     up = _pad_rows(users.astype(jnp.float32), block_n)
-    tp = _pad_cols_edge(_pad_rows(thresholds, block_n), _LANE)
+    thr_t = _pad_rows(thresholds, block_n).T
     # Padded samples carry weight 0 ⇒ contribute nothing to Eq. (1).
     sp = _pad_rows(samples.astype(jnp.float32), 8)
-    wp = _pad_rows(weights.astype(jnp.float32), 8, value=0.0)
-    out = _tb.table_build_kernel_call(up, sp, wp, tp, tau_valid=tau,
-                                      block_n=block_n, interpret=INTERPRET)
-    return out[:n, :tau]
+    wp = _pad_rows(weights.astype(jnp.float32), 8, value=0.0)[:, None]
+    out = _tb.table_build_kernel_call(up, sp, wp, thr_t, block_n=block_n)
+    return out[:, :n].T
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m"))
@@ -147,20 +130,19 @@ def exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array, *,
     """Definition-1 ranks via the streaming kernel. Returns (n,) float32."""
     n, m = users.shape[0], items.shape[0]
     up = _pad_rows(users.astype(jnp.float32), block_n)
+    uq = jax.lax.dot_general(
+        up, q.astype(jnp.float32)[:, None], (((1,), (0,)), ((), ())),
+        precision=dot_precision(),
+        preferred_element_type=jnp.float32)                 # (n_pad, 1)
     # P pads with zero rows: a padded item contributes I[0 > u·q], which is
-    # subtracted exactly below (same f32 dot as the kernel's score_q).
+    # subtracted exactly below (the kernel compares against the same uq).
     ip = _pad_rows(items.astype(jnp.float32), block_m)
     m_pad = ip.shape[0] - m
-    partial = _er.exact_counts_kernel_call(up, ip, q.astype(jnp.float32),
-                                           block_n=block_n, block_m=block_m,
-                                           interpret=INTERPRET)
-    counts = partial.sum(axis=1)[:n]
+    counts = _er.exact_counts_kernel_call(up, ip, uq, block_n=block_n,
+                                          block_m=block_m)
+    counts = counts[:n, 0]
     if m_pad:
-        uq = jax.lax.dot_general(
-            up[:n], q.astype(jnp.float32)[:, None],
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)[:, 0]
-        counts = counts - m_pad * (0.0 > uq).astype(jnp.float32)
+        counts = counts - m_pad * (0.0 > uq[:n, 0]).astype(jnp.float32)
     return 1.0 + counts
 
 
@@ -261,7 +243,7 @@ def _bound_ranks_batched_stored_impl(kind: str, rows, uscale, uslack, qs,
     qt = _pad_rows(qs.astype(jnp.float32), 8).T             # (d, Bp)
     r_lo, r_up, est = _us.bound_ranks_batched_quant_kernel_call(
         kind, up, usc, usl, qt, tp, bp, tsc, tof, tdv, bsc, bof, m=m,
-        tau_valid=tau, block_n=block_n, interpret=INTERPRET)
+        tau_valid=tau, block_n=block_n)
     return r_lo[:n, :B].T, r_up[:n, :B].T, est[:n, :B].T
 
 
@@ -338,8 +320,7 @@ def _bound_ranks_batched_pruned_stored_impl(kind: str, rows, uscale,
     qt = _pad_rows(qs.astype(jnp.float32), 8).T
     r_lo, r_up, est = _us.bound_ranks_batched_quant_masked_kernel_call(
         kind, up, usc, usl, qt, tp, bp, tsc, tof, tdv, bsc, bof,
-        block_ids.astype(jnp.int32), m=m, tau_valid=tau, block_n=block_n,
-        interpret=INTERPRET)
+        block_ids.astype(jnp.int32), m=m, tau_valid=tau, block_n=block_n)
     return r_lo[:, :B].T, r_up[:, :B].T, est[:, :B].T
 
 

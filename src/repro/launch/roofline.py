@@ -76,8 +76,6 @@ class Roofline:
 def analyze(compiled, *, chips: int, model_flops: Optional[float] = None,
             hw: dict = TPU_V5E) -> Roofline:
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):   # older jax: one dict per device
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes(compiled.as_text())

@@ -153,6 +153,7 @@ from repro.data.pipeline import synthetic_embeddings
 from repro.data.mf import MFConfig, embeddings, train_mf
 from repro.data.pipeline import synthetic_ratings
 from repro.index import IndexPersister, MaintenanceLoop, MaintenancePolicy
+from repro.launch import compile_cache
 from repro.obs import registry as obs
 from repro.obs import trace
 from repro.obs.audit import QualityAuditor
@@ -266,6 +267,7 @@ def main():
         ap.error("--kernels is a deprecated alias for --backend fused; "
                  f"it cannot be combined with --backend {args.backend}")
 
+    compile_cache.enable()
     if args.trace:
         trace.enable()
     if args.metrics_port is not None:

@@ -15,6 +15,7 @@ Three contracts, per StorageSpec × backend × batch shape:
     (−128 / −inf) can never be counted by the delta count brackets.
 """
 import dataclasses
+import functools
 import os
 
 import jax
@@ -360,19 +361,43 @@ def test_near_duplicate_cache_key(problem, tables):
         CachingBackend("dense", quantize_key_bits=1)
 
 
-def test_interpret_env_override():
-    """REPRO_INTERPRET flips the kernels' interpret mode without a source
-    edit (the ROADMAP TPU-validation knob)."""
-    import subprocess
-    import sys
-    out = subprocess.run(
-        [sys.executable, "-c",
-         "from repro.kernels import ops; print(ops.INTERPRET)"],
-        env={**os.environ, "REPRO_INTERPRET": "0",
-             "PYTHONPATH": "src" + os.pathsep + os.environ.get(
-                 "PYTHONPATH", "")},
-        capture_output=True, text=True, cwd=os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-    assert out.stdout.strip() == "False", out.stderr
-    from repro.kernels.ops import _interpret_default
-    assert _interpret_default() is True or "REPRO_INTERPRET" in os.environ
+def test_round_bf16_is_round_to_nearest_even():
+    """`round_bf16` rounds f32 to bf16 as an IEEE cast does — ties to
+    even, both signs — for the ties and near-ties the bf16 bucketize
+    meets; host numpy (ml_dtypes) is the reference."""
+    from repro.core.types import round_bf16
+    rng = np.random.default_rng(0)
+    hi = rng.integers(0x3000, 0x4800, size=512).astype(np.uint32) << 16
+    low = np.array([0x8000, 0x7FFF, 0x8001, 0x0000, 0xFFFF], np.uint32)
+    bits = (hi[:, None] | low[None, :]).reshape(-1)
+    x = np.concatenate([bits, bits | 0x80000000]).view(np.float32)
+    want = x.astype(jnp.bfloat16).astype(np.float32)
+    np.testing.assert_array_equal(np.asarray(round_bf16(jnp.asarray(x))),
+                                  want)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(round_bf16)(jnp.asarray(x))), want)
+
+
+def test_interpret_env_override(monkeypatch):
+    """Kernels interpret if and only if the backend is the CPU, decided
+    when a kernel is traced. The old REPRO_INTERPRET variable overrides
+    nothing: on the CPU kernels still interpret with it set to 0, and off
+    the CPU nothing turns interpret mode on."""
+    from repro.kernels import interpret_mode
+    from repro.kernels import user_scores as us
+
+    def traced_interpret():
+        args = (jnp.zeros((256, 8)), jnp.zeros((8, 8)),
+                jnp.zeros((256, 128)), jnp.ones((256, 128)))
+        jaxpr = jax.make_jaxpr(functools.partial(
+            us.bound_ranks_batched_kernel_call, m=10, tau_valid=100))(*args)
+        calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        return bool(calls[0].params["interpret"])
+
+    monkeypatch.setenv("REPRO_INTERPRET", "0")
+    assert jax.default_backend() == "cpu"
+    assert interpret_mode() is True and traced_interpret() is True
+    monkeypatch.setenv("REPRO_INTERPRET", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert interpret_mode() is False and traced_interpret() is False
