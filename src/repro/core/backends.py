@@ -477,14 +477,17 @@ class PrunedBackend(QueryBackend):
         return self.inner.query_batch(rt, users, qs, k=k, c=c, delta=delta)
 
     def query_batch(self, rt, users, qs, *, k, c, delta=None):
-        with trace.span("prune.query", batch=qs.shape[0], k=k):
-            res = self._query_impl(rt, users, qs, k=k, c=c, delta=delta)
-        # publish this batch's accounting (skip rate, kept fractions,
-        # fallback) as gauges — the live half of the §6.3 prune columns
+        # the scheduler's tick id, when called inside its `serve.tick`
+        tick = trace.current_attr("tick")
+        with trace.span("prune.query", tick=tick, batch=qs.shape[0], k=k):
+            res = self._query_impl(rt, users, qs, k=k, c=c, delta=delta,
+                                   tick=tick)
+        # publish this batch's accounting (skip rate, block and fallback
+        # counters) — the live half of the §6.3 prune columns
         self.stats.publish()
         return res
 
-    def _query_impl(self, rt, users, qs, *, k, c, delta=None):
+    def _query_impl(self, rt, users, qs, *, k, c, delta=None, tick=None):
         P = self._pruning
         n = users.shape[0]
         bs = self.block_size
@@ -501,7 +504,7 @@ class PrunedBackend(QueryBackend):
             if (delta.n_add + delta.n_del) / m_base > self.delta_guard:
                 return self._full_scan(rt, users, qs, k=k, c=c, delta=delta,
                                        why="delta-guard", n_blocks=nb)
-        with trace.span("prune.phase_a", n_blocks=nb) as sp_a:
+        with trace.span("prune.phase_a", tick=tick, n_blocks=nb) as sp_a:
             summary = self.summary_for(rt, users)
             if delta is None:
                 keep, _ = P.phase_a(summary, qs, k=k, block_size=bs)
@@ -511,7 +514,8 @@ class PrunedBackend(QueryBackend):
                                     n_del=float(delta.n_del),
                                     user_live=delta.user_live,
                                     with_live=True)
-            keep_np = np.asarray(keep)                      # host sync
+            with trace.span("prune.keep_sync", tick=tick):
+                keep_np = np.asarray(keep)                  # host sync
             union = np.flatnonzero(keep_np.any(axis=0))
             per_q = float(keep_np.mean())
             sp_a.set(kept_union=int(union.size))
@@ -529,7 +533,7 @@ class PrunedBackend(QueryBackend):
         self.stats = P.PruneStats(n_blocks=nb, kept_union=int(union.size),
                                   kept_per_query=per_q)
         min_blocks = -(-k // bs)
-        with trace.span("prune.phase_b", kept=int(union.size),
+        with trace.span("prune.phase_b", tick=tick, kept=int(union.size),
                         n_blocks=nb):
             if sharded:
                 return self._sharded_query(rt, users, qs, keep_np, k=k,

@@ -231,20 +231,25 @@ class PruneStats:
         return 1.0 - self.union_fraction
 
     def publish(self, registry=None) -> None:
-        """Mirror this batch's accounting into metrics gauges (the live
-        half of the bench's §6.3 prune columns): `prune_skip_rate`,
-        `prune_kept_per_query`, and per-reason fallback counters."""
+        """Mirror this batch's accounting into the metrics registry: the
+        `prune_skip_rate` gauge (last batch), per-reason fallback
+        counters, and the block counters whose deltas give any window's
+        skip rate, 1 − Δprune_blocks_executed_total / Δprune_blocks_total."""
         from repro.obs import registry as obs
         reg = registry if registry is not None else obs.get_default()
         reg.gauge("prune_skip_rate",
                   "1 - kept-union fraction of the last pruned batch"
                   ).set(self.skip_rate)
-        reg.gauge("prune_kept_per_query",
-                  "mean per-query kept-block fraction, last batch"
-                  ).set(self.kept_per_query)
         reg.counter("prune_batches_total",
                     "pruned query_batch calls",
                     labels={"fallback": self.fallback or "none"}).inc()
+        reg.counter("prune_blocks_total",
+                    "summary blocks over pruned query_batch calls"
+                    ).inc(self.n_blocks)
+        # a fallback scanned every block, whatever phase A kept
+        reg.counter("prune_blocks_executed_total",
+                    "blocks scanned: the kept union, or all on fallback"
+                    ).inc(self.n_blocks if self.fallback else self.kept_union)
 
 
 def _pad_rows(x: jax.Array, total: int, value) -> jax.Array:

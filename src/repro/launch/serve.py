@@ -40,6 +40,8 @@ Prometheus) at it::
 Key series: `serve_request_latency_ms` (histogram; p50/p99 in the JSON
 snapshot), `serve_queue_depth` / `serve_rejected_total` (back-pressure),
 `cache_hits_total` / `cache_misses_total`, `prune_skip_rate`,
+`prune_blocks_total` / `prune_blocks_executed_total` (window skip rate
+= 1 − the ratio of their deltas),
 `query_compiled_programs` (flat slope in steady state = no recompile
 storm), and `maintenance_rebuilds_total` / `maintenance_build_ms`.
 

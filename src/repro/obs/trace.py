@@ -20,7 +20,13 @@ tracing works on builds without the profiler extras).
 
 Cross-thread intervals (a queue wait measured at dispatch for a request
 submitted on a client thread) cannot be a `with` block; `event()` records
-one retroactively from (t_start, duration).
+one retroactively from (t_start, duration). Such a record never reaches
+the profiler trace, so an interval that must line up with the device
+timeline is a `with` span on the thread where it happens.
+
+Spans of one unit of work share an identifier attr (the scheduler's
+`tick=<n>`); a layer called inside such a span reads it back with
+`current_attr("tick")` instead of taking it as an argument.
 
 Usage::
 
@@ -41,8 +47,8 @@ import time
 from collections import deque
 from typing import Deque, List, Optional, Tuple
 
-__all__ = ["SpanRecord", "span", "event", "enable", "disable",
-           "is_enabled", "spans", "clear", "set_capacity"]
+__all__ = ["SpanRecord", "span", "event", "current_attr", "enable",
+           "disable", "is_enabled", "spans", "clear", "set_capacity"]
 
 _enabled = False
 _profiler = False
@@ -115,7 +121,7 @@ class _Span:
                 self._prof = None
             # host and device timelines align because the annotation
             # brackets exactly this span's body
-        _stack().append(self.name)
+        _stack().append(self)
         self.t0 = time.monotonic()
         return self
 
@@ -123,10 +129,10 @@ class _Span:
         t1 = time.monotonic()
         stack = _stack()
         # tolerate enable()/disable() races mid-span: only pop our frame
-        if stack and stack[-1] is self.name:
+        if stack and stack[-1] is self:
             stack.pop()
         depth = len(stack)
-        parent = stack[-1] if stack else None
+        parent = stack[-1].name if stack else None
         if self._prof is not None:
             try:
                 self._prof.__exit__(*exc)
@@ -158,9 +164,20 @@ def event(name: str, t_start: float, duration_s: float, **attrs) -> None:
     stack = _stack()
     _buffer.append(SpanRecord(
         name=name, t_start=t_start, duration_s=duration_s,
-        depth=len(stack), parent=stack[-1] if stack else None,
+        depth=len(stack), parent=stack[-1].name if stack else None,
         thread=threading.current_thread().name,
         attrs=tuple(sorted(attrs.items()))))
+
+
+def current_attr(key: str):
+    """The `key` attr of the innermost open span on this thread that
+    carries it, else None (always None while tracing is disabled)."""
+    if not _enabled:
+        return None
+    for sp in reversed(_stack()):
+        if key in sp.attrs:
+            return sp.attrs[key]
+    return None
 
 
 def enable(profiler: bool = False) -> None:

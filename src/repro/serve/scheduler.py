@@ -123,6 +123,22 @@ schedule (the A/B baseline `benchmarks/perf_engine.py --serve
 --saturate` measures overlap against). `TickStats.inflight` records the
 pipeline occupancy at each dispatch; `ServeStats.overlap_efficiency` is
 the fraction of ticks that actually overlapped another.
+
+Wait spans
+----------
+With tracing on (`repro.obs.trace`), every wait on the serving path is a
+`with` span on the thread where it happens, so the profiler trace can
+say what the host was doing while the device sat idle: the dispatcher's
+`serve.idle` (empty queue), `serve.fill_wait` (holding a partial head
+tick for up to `max_wait_ms`) and `serve.pipeline_full` (no in-flight
+slot), and, inside the completion stage's `serve.transfer`,
+`serve.ready` (the tick's device work) then `serve.d2h` (the copy
+alone). Each dispatched tick takes a sequence number, the attr
+`tick=<n>` on its `serve.tick`, its requests' `serve.queue_wait`
+events, the backend's spans inside the tick and its completion spans.
+With tracing off the completion stage makes its single `device_get` as
+before; the ready/copy split costs a `block_until_ready` and is made
+only while tracing.
 """
 from __future__ import annotations
 
@@ -285,12 +301,13 @@ class _InflightTick:
     consumes. `res` holds the engine call's UNMATERIALIZED device arrays
     (JAX async dispatch) — nothing here has blocked on the device yet."""
 
-    __slots__ = ("reqs", "res", "snap", "epoch", "k", "c_eff", "depth",
-                 "rejected", "expired", "level", "t_dispatch", "compiles",
-                 "inflight")
+    __slots__ = ("seq", "reqs", "res", "snap", "epoch", "k", "c_eff",
+                 "depth", "rejected", "expired", "level", "t_dispatch",
+                 "compiles", "inflight")
 
-    def __init__(self, reqs, res, snap, epoch, k, c_eff, depth, rejected,
-                 expired, level, t_dispatch, compiles):
+    def __init__(self, seq, reqs, res, snap, epoch, k, c_eff, depth,
+                 rejected, expired, level, t_dispatch, compiles):
+        self.seq = seq          # the tick id its spans carry (`tick=`)
         self.reqs = reqs
         self.res = res
         self.snap = snap
@@ -373,8 +390,6 @@ class MicroBatcher:
             "serve_compiles_total", "XLA programs compiled during ticks")
         self._m_depth = reg.gauge(
             "serve_queue_depth", "queue length at the last tick cut")
-        self._m_fill = reg.gauge(
-            "serve_tick_fill_ratio", "fill ratio of the last tick")
         self._m_latency = reg.histogram(
             "serve_request_latency_ms", "submit → resolve latency")
         self._m_wait = reg.histogram(
@@ -394,6 +409,7 @@ class MicroBatcher:
         self._drain_deadline = None  # monotonic bound on close() draining
         self._flush = False
         self._busy = False          # a tick is being dispatched right now
+        self._tick_seq = 0          # next tick id (dispatcher thread only)
         self._ticks: List[TickStats] = []
         self._rejected_total = 0
         self._rejected_since_tick = 0
@@ -667,8 +683,10 @@ class MicroBatcher:
             reqs = None
             terminal = False
             with self._cond:
-                while not self._queue and not self._stop:
-                    self._cond.wait()
+                if not self._queue and not self._stop:
+                    with trace.span("serve.idle"):
+                        while not self._queue and not self._stop:
+                            self._cond.wait()
                 now = time.monotonic()
                 # Deadline sweep FIRST: an expired request must not be
                 # chosen as the head nor occupy a tick slot.
@@ -701,12 +719,16 @@ class MicroBatcher:
                     # bounded drain that expires while waiting falls
                     # through (reqs stays None) to the top-of-loop shed
                     # instead of cutting past the depth bound.
-                    while len(self._inflight) >= self.pipeline_depth:
-                        if (self._stop and self._drain_deadline is not None
-                                and time.monotonic()
-                                >= self._drain_deadline):
-                            break
-                        self._cond.wait(timeout=0.05)
+                    if len(self._inflight) >= self.pipeline_depth:
+                        with trace.span("serve.pipeline_full"):
+                            while (len(self._inflight)
+                                   >= self.pipeline_depth):
+                                if (self._stop
+                                        and self._drain_deadline is not None
+                                        and time.monotonic()
+                                        >= self._drain_deadline):
+                                    break
+                                self._cond.wait(timeout=0.05)
                     if len(self._inflight) < self.pipeline_depth:
                         # a budget may have lapsed during the slot wait
                         expired += self._sweep_expired(time.monotonic())
@@ -714,12 +736,16 @@ class MicroBatcher:
                             and self._queue):
                         head = self._queue[0]
                         deadline = head.t_submit + self.max_wait_ms / 1e3
-                        while (self._full_key() is None
-                               and not (self._stop or self._flush)):
-                            remaining = deadline - time.monotonic()
-                            if remaining <= 0:
-                                break
-                            self._cond.wait(timeout=remaining)
+                        remaining = deadline - time.monotonic()
+                        if (remaining > 0 and self._full_key() is None
+                                and not (self._stop or self._flush)):
+                            with trace.span("serve.fill_wait"):
+                                while remaining > 0:
+                                    self._cond.wait(timeout=remaining)
+                                    if (self._full_key() is not None
+                                            or self._stop or self._flush):
+                                        break
+                                    remaining = deadline - time.monotonic()
                         # late sweep: a request whose budget ran out
                         # DURING the coalescing wait must not take a
                         # tick slot
@@ -786,6 +812,8 @@ class MicroBatcher:
         host sync on this thread (the JAX dispatch returns unmaterialized
         device arrays; `_complete` performs the single blocking D2H)."""
         t_dispatch = time.monotonic()
+        seq = self._tick_seq
+        self._tick_seq += 1
         k, c = reqs[0].key
         # rung 2+ of the degrade ladder dispatches at a WIDENED contract:
         # the result is a valid c_eff-approximation, reported as such
@@ -797,12 +825,13 @@ class MicroBatcher:
                 and getattr(self.engine, "current_snapshot", None)
                 is not None):
             self._dispatch_cache_only(reqs, depth, rejected, expired,
-                                      level, t_dispatch)
+                                      level, t_dispatch, seq)
             return
         epoch = None
         snap = None
         programs_before = _program_count()
-        sp = trace.span("serve.tick", batch=len(reqs), depth=depth, k=k)
+        sp = trace.span("serve.tick", tick=seq, batch=len(reqs),
+                        depth=depth, k=k)
         try:
             with sp:
                 if faults.ACTIVE is not None:
@@ -815,7 +844,7 @@ class MicroBatcher:
                     # records attribute to the tick that served them
                     for r in reqs:
                         trace.event("serve.queue_wait", r.t_submit,
-                                    t_dispatch - r.t_submit, k=k)
+                                    t_dispatch - r.t_submit, k=k, tick=seq)
                 qs = self._assemble_block(reqs)
                 # Pin ONE index snapshot for the whole tick (module doc):
                 # a hot-swap concurrent with this dispatch lands between
@@ -853,7 +882,7 @@ class MicroBatcher:
         # thread, so the delta cleanly brackets this tick's dispatch even
         # with other ticks in flight.
         tick = _InflightTick(
-            reqs, res, snap, epoch, k, c_eff, depth, rejected, expired,
+            seq, reqs, res, snap, epoch, k, c_eff, depth, rejected, expired,
             level, t_dispatch,
             compiles=max(0, _program_count() - programs_before))
         with self._cond:
@@ -899,7 +928,7 @@ class MicroBatcher:
         reqs = t.reqs
         t_transfer = time.monotonic()
         try:
-            with trace.span("serve.transfer", batch=len(reqs),
+            with trace.span("serve.transfer", tick=t.seq, batch=len(reqs),
                             epoch=t.epoch, inflight=t.inflight):
                 if faults.ACTIVE is not None:
                     faults.fire("serve.transfer")
@@ -908,7 +937,15 @@ class MicroBatcher:
                 # zero-copy, where B×fields device slices would dominate
                 # the tick cost. A deferred dispatch error (async
                 # runtime) also surfaces here and is failed typed below.
-                host = jax.device_get(t.res)
+                if trace.is_enabled():
+                    # traced: the wait for the tick's device work and
+                    # the copy as spans of their own (module doc)
+                    with trace.span("serve.ready", tick=t.seq):
+                        jax.block_until_ready(t.res)
+                    with trace.span("serve.d2h", tick=t.seq):
+                        host = jax.device_get(t.res)
+                else:
+                    host = jax.device_get(t.res)
         except Exception as e:
             # Fail exactly THIS tick's futures; later in-flight ticks
             # keep completing. Reject/expiry attribution re-credits to
@@ -940,7 +977,6 @@ class MicroBatcher:
         if tick.compiles:
             self._m_compiles.inc(tick.compiles)
         self._m_depth.set(t.depth)
-        self._m_fill.set(tick.fill_ratio)
         self._m_transfer.observe(transfer_ms)
         for r in reqs:
             self._m_wait.observe((t.t_dispatch - r.t_submit) * 1e3)
@@ -958,7 +994,7 @@ class MicroBatcher:
 
     def _dispatch_cache_only(self, reqs: List[_Request], depth: int,
                              rejected: int, expired: int, level: int,
-                             t_dispatch: float):
+                             t_dispatch: float, seq: int):
         """Degrade rung 3: answer LRU hits against the pinned snapshot,
         shed misses with `QueueFull` (reject reason `degraded`).
 
@@ -976,8 +1012,8 @@ class MicroBatcher:
         rt, users, delta = snap.rank_table, snap.query_users(), snap.corr
         hits: List[Tuple[_Request, object, float]] = []
         misses: List[_Request] = []
-        with trace.span("serve.cache_only", batch=len(reqs), depth=depth,
-                        k=k, epoch=epoch, level=level):
+        with trace.span("serve.cache_only", tick=seq, batch=len(reqs),
+                        depth=depth, k=k, epoch=epoch, level=level):
             for r in reqs:
                 row = np.asarray(r.q)       # host already (PR 10 submit)
                 # entries may have been cached at the base contract or at
@@ -1017,7 +1053,6 @@ class MicroBatcher:
             self._ticks.append(tick)
         self._m_ticks.inc()
         self._m_depth.set(depth)
-        self._m_fill.set(tick.fill_ratio)
         if misses:
             self._m_rejected.inc(len(misses))
             self._m_reject_reason["degraded"].inc(len(misses))

@@ -15,6 +15,7 @@ import json
 import math
 import sys
 import threading
+import time
 import urllib.request
 
 import numpy as np
@@ -458,6 +459,122 @@ def test_serving_metrics_flow_into_default_registry(serve_setup):
     assert reg.counter("serve_requests_total").value == before + len(qs)
     assert reg.histogram("serve_request_latency_ms").count > 0
     assert reg.histogram("serve_queue_wait_ms").count > 0
+
+
+WAIT_SPANS = ("serve.idle", "serve.fill_wait", "serve.pipeline_full")
+
+
+def _wait_run(serve_setup, traced):
+    """One run that makes the dispatcher wait in each way: a lone
+    request is held as a partial head tick for `max_wait_ms`, and a full
+    group arrives while that tick's transfer sleeps with one slot in
+    flight (`pipeline_depth` 1). Returns the span records, the threads
+    that called `jax.block_until_ready`, and the ticks' batch sizes."""
+    import jax
+    from repro.core.engine import ReverseKRanksEngine
+    from repro.serve import MicroBatcher, faults
+
+    cached, qs = serve_setup
+    eng = ReverseKRanksEngine(users=cached.users, rank_table=cached.rank_table,
+                              config=cached.config, backend="dense")
+    real = jax.block_until_ready
+    callers = []
+
+    def counting(x):
+        callers.append(threading.current_thread().name)
+        return real(x)
+
+    trace.disable()
+    trace.clear()
+    faults.install(faults.FaultPlan(seed=0, rules=[
+        faults.FaultRule("serve.transfer", mode="sleep", rate=1.0,
+                         latency_ms=300.0)]))
+    try:
+        if traced:
+            trace.enable()
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(jax, "block_until_ready", counting)
+            with MicroBatcher(eng, max_batch=8, max_wait_ms=200.0,
+                              pipeline_depth=1) as mb:
+                futs = [mb.submit(qs[0], 7, 2.0)]
+                deadline = time.monotonic() + 60
+                while not mb._inflight and time.monotonic() < deadline:
+                    time.sleep(0.001)
+                futs += [mb.submit(q, 7, 2.0) for q in qs]
+                for f in futs:
+                    assert f.result(timeout=120).indices.shape == (7,)
+                batches = [t.batch for t in mb.tick_log]
+        return trace.spans(), callers, batches
+    finally:
+        faults.clear()
+        trace.disable()
+        trace.clear()
+
+
+@pytest.fixture(scope="module")
+def traced_waits(serve_setup):
+    return _wait_run(serve_setup, traced=True)
+
+
+def test_dispatcher_waits_are_spans(traced_waits):
+    """Each way the dispatcher waits is a top-level span on its thread:
+    an empty queue, a partial head tick held for `max_wait_ms`, and a
+    full pipeline."""
+    recs, _, batches = traced_waits
+    assert batches == [1, 8]
+    for name in WAIT_SPANS:
+        rs = [r for r in recs if r.name == name]
+        assert rs, f"no {name} span"
+        for r in rs:
+            assert r.thread == "microbatcher" and r.depth == 0
+    (fill,) = [r for r in recs if r.name == "serve.fill_wait"]
+    assert fill.duration_s >= 0.15          # held for max_wait_ms 200
+    assert all(r.duration_s > 0 for r in recs
+               if r.name == "serve.pipeline_full")
+
+
+def test_ready_and_d2h_split_the_transfer(traced_waits):
+    """Traced, the completion stage's blocking transfer is the wait for
+    the tick's device work (`serve.ready`), then the copy alone
+    (`serve.d2h`), both children of `serve.transfer`."""
+    recs, callers, batches = traced_waits
+    ready = [r for r in recs if r.name == "serve.ready"]
+    d2h = [r for r in recs if r.name == "serve.d2h"]
+    assert len(ready) == len(d2h) == len(batches)
+    for r in ready + d2h:
+        assert r.parent == "serve.transfer" and r.depth == 1
+        assert r.thread == "microbatcher-complete"
+    for a, b in zip(ready, d2h):
+        assert dict(a.attrs)["tick"] == dict(b.attrs)["tick"]
+        assert a.t_start + a.duration_s <= b.t_start
+    assert callers.count("microbatcher-complete") == len(batches)
+
+
+def test_a_ticks_spans_share_its_id(traced_waits):
+    """Every span of a tick carries its `tick` id, and every queue-wait
+    event names a dispatched tick, one per request it served."""
+    recs, _, batches = traced_waits
+    ticks = {dict(r.attrs)["tick"]: dict(r.attrs)["batch"]
+             for r in recs if r.name == "serve.tick"}
+    assert sorted(ticks) == list(range(len(batches)))
+    for name in ("serve.transfer", "serve.ready", "serve.d2h"):
+        assert sorted(dict(r.attrs)["tick"] for r in recs
+                      if r.name == name) == sorted(ticks)
+    waits = [dict(r.attrs)["tick"] for r in recs
+             if r.name == "serve.queue_wait"]
+    for tick, batch in ticks.items():
+        assert waits.count(tick) == batch
+    assert len(waits) == sum(batches)
+
+
+def test_untraced_completion_makes_the_one_transfer_call(serve_setup):
+    """With tracing off the same run records nothing, and the completion
+    stage makes no `block_until_ready` call: its single `device_get` is
+    the whole transfer, as without the spans."""
+    recs, callers, batches = _wait_run(serve_setup, traced=False)
+    assert recs == []
+    assert batches == [1, 8]
+    assert "microbatcher-complete" not in callers
 
 
 @pytest.mark.slow

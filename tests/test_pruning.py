@@ -344,6 +344,74 @@ def test_registry_and_engine_spec():
         BK.get_backend("pruned:no-such-inner")
 
 
+# --------------------------------------------------- spans / counters
+def test_keep_sync_is_timed_inside_phase_a(problem, regimes):
+    """The blocking read of phase A's keep mask is its own span inside
+    `prune.phase_a`, and every prune span carries the id of the tick
+    it runs inside."""
+    from repro.obs import trace
+    users, items = problem
+    cfg, rt, c = regimes["non_guaranteed"]
+    eng = pruned_engine(users, rt, cfg, "dense", max_union_frac=1.1)
+    qs = items[:8] * (1.0 + 1e-4 * jax.random.normal(
+        jax.random.PRNGKey(5), (8, D), jnp.float32))
+    trace.disable()
+    trace.clear()
+    trace.enable()
+    try:
+        with trace.span("serve.tick", tick=5):
+            eng.query_batch(qs, k=K, c=c)
+        recs = trace.spans()
+    finally:
+        trace.disable()
+        trace.clear()
+    (sync,) = [r for r in recs if r.name == "prune.keep_sync"]
+    (phase_a,) = [r for r in recs if r.name == "prune.phase_a"]
+    assert sync.parent == "prune.phase_a" and sync.depth == phase_a.depth + 1
+    assert phase_a.t_start <= sync.t_start
+    assert (sync.t_start + sync.duration_s
+            <= phase_a.t_start + phase_a.duration_s)
+    prune = [r for r in recs if r.name.startswith("prune.")]
+    assert {r.name for r in prune} == {"prune.query", "prune.phase_a",
+                                       "prune.keep_sync", "prune.phase_b"}
+    assert all(dict(r.attrs)["tick"] == 5 for r in prune)
+
+
+def test_block_counters_give_the_window_skip_rate(problem, regimes):
+    """`prune_blocks_total` and `prune_blocks_executed_total` move by the
+    blocks of each batch and the blocks it scanned (all of them on a
+    fallback), so one counter delta gives any window's skip rate."""
+    from repro.obs import registry as obs
+    users, items = problem
+    cfg, rt, c = regimes["non_guaranteed"]
+    pruning = pruned_engine(users, rt, cfg, "dense", max_union_frac=1.1)
+    falling_back = pruned_engine(users, rt, cfg, "dense", max_union_frac=0.0)
+    batches = [(pruning, items[:8]), (falling_back, items[:8]),
+               (pruning, off_grid_queries(items, 8)),
+               (pruning, items[100:104])]
+    old = obs.get_default()
+    reg = obs.MetricsRegistry()
+    obs.set_default(reg)
+    total = executed = 0
+    fallbacks = []
+    try:
+        for eng, qs in batches:
+            eng.query_batch(qs, k=K, c=c)
+            st = eng._backend.stats
+            fallbacks.append(st.fallback)
+            total += st.n_blocks
+            executed += st.n_blocks if st.fallback else st.kept_union
+    finally:
+        obs.set_default(old)
+    assert "dense" in fallbacks and "" in fallbacks
+    assert reg.counter("prune_blocks_total").value == total
+    assert reg.counter("prune_blocks_executed_total").value == executed
+    skip = 1.0 - (reg.counter("prune_blocks_executed_total").value
+                  / reg.counter("prune_blocks_total").value)
+    assert skip == pytest.approx(1.0 - executed / total)
+    assert 0.0 < skip < 1.0
+
+
 # --------------------------------------- geometry sketches (PR 6)
 SPECS = ("float32", "bfloat16", "int8")
 

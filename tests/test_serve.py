@@ -141,7 +141,9 @@ def test_tick_log_and_stats_return_copies(problem, rank_table, queries):
     list (or calling them concurrently with dispatches) must never
     reach the scheduler's live `_ticks` deque."""
     eng = _engine(problem, rank_table, "dense")
-    with MicroBatcher(eng, max_batch=MAX_BATCH, max_wait_ms=10.0) as mb:
+    # a wait no run reaches: each burst of MAX_BATCH cuts one tick when
+    # it is full, however slowly the submits arrive
+    with MicroBatcher(eng, max_batch=MAX_BATCH, max_wait_ms=600e3) as mb:
         for f in [mb.submit(q, K, C) for q in queries]:
             f.result(timeout=120)
         log = mb.tick_log
