@@ -96,7 +96,7 @@ class QueryBackend:
     def select(self, rt: RankTable, r_lo: jax.Array, r_up: jax.Array,
                est: jax.Array, *, k: int, c: float) -> QueryResult:
         """§4.3 steps 2-3 on (B, n) bounds → QueryResult with leading B axis."""
-        return query_mod.select_topk(r_lo, r_up, est, k=k, c=c, m_items=rt.m)
+        return query_mod._select_topk_jit(r_lo, r_up, est, rt.m, k, c)
 
     def build_index(self, users: jax.Array, items: jax.Array,
                     cfg: RankTableConfig, key: jax.Array) -> RankTable:
@@ -123,8 +123,8 @@ class QueryBackend:
         scores, slack = query_mod.user_scores_batch(users, qs)  # (n, B)
         r_lo, r_up, est = rt_mod.apply_delta_corrections(
             scores, r_lo.T, r_up.T, est.T, delta, slack=slack)
-        return query_mod.select_topk(r_lo.T, r_up.T, est.T, k=k, c=c,
-                                     m_items=delta.selection_m())
+        return query_mod._select_topk_jit(r_lo.T, r_up.T, est.T,
+                                          delta.selection_m(), k, c)
 
     def query_batch(self, rt: RankTable, users: jax.Array, qs: jax.Array,
                     *, k: int, c: float,

@@ -451,7 +451,9 @@ def select_topk(r_lo: jax.Array, r_up: jax.Array, est: jax.Array, *, k: int,
     (`kernels.ops.query_fused*`).
 
     Shape-polymorphic: pass (n,) arrays for one query or (B, n) arrays for
-    a batch; every QueryResult field gains the same leading axes.
+    a batch; every QueryResult field gains the same leading axes. Call it
+    inside a jit (or through `_select_topk_jit`): step 2 is O(n) only
+    compiled (see `kth_smallest`).
     """
     R_lo_k = kth_smallest(r_lo, k)                          # step 2: O(n)
     R_up_k = kth_smallest(r_up, k)
@@ -500,6 +502,11 @@ def _delta_bounds_batch(rt: RankTable, users, qs: jax.Array,
 @functools.partial(jax.jit, static_argnames=("k",))
 def _select_topk_jit(r_lo, r_up, est, m_items, k: int, c: float
                      ) -> QueryResult:
+    """`select_topk` as one compiled program, for callers whose bounds come
+    from outside a jit (the fused kernel path, the backends' generic
+    select and delta paths). Compiled, XLA drops the unused half of
+    `kth_smallest`'s partition; called eagerly, that half sorts every
+    (B, n) bound array in full."""
     return select_topk(r_lo, r_up, est, k=k, c=c, m_items=m_items)
 
 

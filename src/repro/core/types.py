@@ -547,7 +547,11 @@ def kth_smallest(x: jax.Array, k: int) -> jax.Array:
     Implemented with jnp.partition rather than top_k on the negation: an
     order STATISTIC needs no indices, and XLA's CPU backend lowers a
     values-only top_k to a full O(n log n) sort (~100× slower at
-    (16, 16k)); partition stays O(n) and returns the identical value.
+    (16, 16k)). jnp.partition is top_k(-x, k) joined with top_k(x, n − k);
+    only inside a jit does XLA drop the unused second half, so partition
+    stays O(n) there and returns the identical value. Called eagerly it
+    returns the whole joined array, which sorts the full last axis:
+    callers run it compiled (`query._select_topk_jit`).
     """
     return jnp.partition(x, k - 1, axis=-1)[..., k - 1]
 

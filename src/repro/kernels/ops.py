@@ -148,25 +148,26 @@ def exact_ranks(users: jax.Array, items: jax.Array, q: jax.Array, *,
 
 def query_fused(rt: RankTable, users: jax.Array, q: jax.Array, k: int,
                 c: float) -> QueryResult:
-    """§4.3 query with step 1 on the fused Pallas kernel; steps 2-3 (O(n)
-    top-k/filter tail) in plain jnp — identical selection semantics to
-    repro.core.query.query."""
-    from repro.core.query import select_topk
+    """§4.3 query with step 1 on the fused Pallas kernel; steps 2-3 (the
+    top-k/filter tail) as one compiled selection program — identical
+    selection semantics to repro.core.query.query."""
+    from repro.core.query import _select_topk_jit
     m = int(rt.m)
     r_lo, r_up, est = bound_ranks(users, q, rt.thresholds, rt.table, m=m)
-    return select_topk(r_lo, r_up, est, k=k, c=c, m_items=rt.m)
+    return _select_topk_jit(r_lo, r_up, est, rt.m, k, c)
 
 
 def query_fused_batch(rt: RankTable, users, qs: jax.Array,
                       k: int, c: float) -> QueryResult:
     """Batched §4.3 queries with step 1 on the batched Pallas kernel —
     one table pass for the whole (B, d) query block; selection (steps 2-3)
-    via the shared shape-polymorphic `select_topk`. Every QueryResult
-    field gains a leading B axis. Dispatches on the storage spec
-    (`bound_ranks_batched_stored`); the f32 spec is the pre-spec path."""
-    from repro.core.query import select_topk
+    via the shared shape-polymorphic `select_topk`, compiled as one program
+    (`_select_topk_jit`). Every QueryResult field gains a leading B axis.
+    Dispatches on the storage spec (`bound_ranks_batched_stored`); the f32
+    spec is the pre-spec path."""
+    from repro.core.query import _select_topk_jit
     r_lo, r_up, est = bound_ranks_batched_stored(users, qs, rt)
-    return select_topk(r_lo, r_up, est, k=k, c=c, m_items=rt.m)
+    return _select_topk_jit(r_lo, r_up, est, rt.m, k, c)
 
 
 # NOTE: there is deliberately no query_fused_*_delta here — the fused

@@ -1,5 +1,8 @@
 """Per-kernel validation: pallas_call (interpret=True) vs ref.py oracles,
 swept over shapes and dtypes, plus integration vs repro.core."""
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -70,6 +73,83 @@ def test_query_fused_selection_matches_core():
     np.testing.assert_array_equal(np.asarray(a.indices), np.asarray(b.indices))
     np.testing.assert_allclose(np.asarray(a.est_rank),
                                np.asarray(b.est_rank), rtol=1e-5)
+
+
+def _bits(x) -> np.ndarray:
+    """Bit pattern of an array, so -0.0 and NaN compare exactly."""
+    x = np.asarray(x)
+    return x.view(np.uint32) if x.dtype == np.float32 else x
+
+
+@pytest.mark.parametrize("B", [1, 16])
+@pytest.mark.parametrize("k", [1, 10])
+def test_compiled_selection_bit_identical_to_eager(B, k):
+    """The compiled §4.3 selection returns the eager `select_topk`'s
+    QueryResult bit for bit, on bounds with many ties; and the fused
+    backend's batched query selects as the dense one does."""
+    from repro.core import backends as BK
+    from repro.core.query import _select_topk_jit, select_topk
+    n, m = 600, 40
+    kl, kw, ke = jax.random.split(jax.random.PRNGKey(100 * B + k), 3)
+    # integer bounds from a narrow range: every order statistic is tied
+    r_lo = jax.random.randint(kl, (B, n), 1, 12).astype(jnp.float32)
+    r_up = r_lo + jax.random.randint(kw, (B, n), 0, 6).astype(jnp.float32)
+    est = jnp.round(4.0 * jax.random.uniform(ke, (B, n), minval=r_lo,
+                                             maxval=r_up)) / 4.0
+    m_items = jnp.asarray(m, jnp.int32)
+    for c in (1.0, 2.0):
+        want = select_topk(r_lo, r_up, est, k=k, c=c, m_items=m_items)
+        got = _select_topk_jit(r_lo, r_up, est, m_items, k, c)
+        for name in want._fields:
+            np.testing.assert_array_equal(
+                _bits(getattr(got, name)), _bits(getattr(want, name)),
+                err_msg=name)
+
+    users, items = make_problem(jax.random.PRNGKey(42), 512, 400, 16)
+    rt = _table_for(users, items, 16)
+    base = items[(1 + jnp.arange(B) * 17) % items.shape[0]]
+    # off the threshold grid, so both step-1 paths bucketize alike
+    qs = base * (1.0 + 1e-4 * jax.random.normal(
+        jax.random.PRNGKey(7 + B), base.shape, jnp.float32))
+    dense = BK.get_backend("dense").query_batch(rt, users, qs, k=k, c=1.0)
+    fused = BK.get_backend("fused").query_batch(rt, users, qs, k=k, c=1.0)
+    for name in ("indices", "R_lo_k", "R_up_k"):
+        np.testing.assert_array_equal(
+            _bits(getattr(fused, name)), _bits(getattr(dense, name)),
+            err_msg=name)
+
+
+def test_fused_selection_runs_compiled(monkeypatch):
+    """The fused path selects inside one compiled program: `select_topk`
+    only ever sees tracers, and the compiled selection at (16, 4096),
+    k 10, holds no array n − k wide — the half of `jnp.partition` that
+    sorts the rest of the axis is gone. Eager, that half is returned."""
+    query_mod = sys.modules["repro.core.query"]  # the package exports query()
+    eager_calls = []
+    real = query_mod.select_topk
+
+    def spy(r_lo, *args, **kw):
+        if not isinstance(r_lo, jax.core.Tracer):
+            eager_calls.append(r_lo.shape)
+        return real(r_lo, *args, **kw)
+
+    monkeypatch.setattr(query_mod, "select_topk", spy)
+    users, items = make_problem(jax.random.PRNGKey(3), 300, 200, 16)
+    rt = _table_for(users, items, 16)
+    ops.query_fused_batch(rt, users, items[:4], k=10, c=2.0)
+    ops.query_fused(rt, users, items[5], k=10, c=2.0)
+    assert eager_calls == []
+
+    B, n, k = 16, 4096, 10
+    bounds = jax.ShapeDtypeStruct((B, n), jnp.float32)
+    m_items = jax.ShapeDtypeStruct((), jnp.int32)
+    hlo = query_mod._select_topk_jit.lower(
+        bounds, bounds, bounds, m_items, k, 2.0).compile().as_text()
+    assert re.search(rf"\b{n - k}\b", hlo) is None
+    # control: the same partition with its whole output live holds it
+    full = jax.jit(lambda x: jnp.partition(x, k - 1, axis=-1)).lower(
+        bounds).compile().as_text()
+    assert re.search(rf"\b{n - k}\b", full) is not None
 
 
 # ---------------------------------------------------------------- table_build
