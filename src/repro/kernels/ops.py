@@ -1,7 +1,9 @@
 """jit'd public wrappers around the Pallas kernels (padding + reduction).
 
 These are the entry points the engine uses; each pads inputs to kernel
-tile multiples, invokes the raw pallas_call, and undoes the padding.
+tile multiples where it must, invokes the raw pallas_call, and undoes
+the padding. The f32 scan pads only its queries: it reads the resident
+index in place, transposed views of the chip's users-on-lanes layout.
 Kernels run compiled on an accelerator and interpreted on the CPU
 (`repro.kernels.interpret_mode`); the same code path serves both.
 """
@@ -54,26 +56,27 @@ def bound_ranks_batched(users: jax.Array, qs: jax.Array,
                         thresholds: jax.Array, table: jax.Array, *, m: int,
                         block_n: int = 256
                         ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Batched fused step 1: one (block_n, d) × (d, B) MXU matmul per user
+    """Batched fused step 1: one (B, d) × (d, block_n) MXU matmul per user
     tile, all B queries bucketized against the same VMEM-resident
     threshold/table tile — the (n, d+2τ) HBM stream is read ONCE for the
     whole batch instead of once per query.
 
+    The kernel reads the index where it lies: `users.T` (d, n) and
+    `thresholds.T`/`table.T` (τ, n) are views of the chip's default
+    layout for these shapes (users on lanes), so nothing is copied, padded
+    or transposed per call. The grid's last tile is ragged when block_n
+    does not divide n; only the queries pad, to a sublane multiple (zero
+    rows, dropped below).
+
     qs is (B, d); returns (r↓, r↑, est), each (B, n) float32 (query-major,
     the `QueryBackend.bound_ranks` orientation).
     """
-    n, tau = thresholds.shape[0], thresholds.shape[1]
     B = qs.shape[0]
-    up = _pad_rows(users.astype(jnp.float32), block_n)
-    tp = _pad_cols_edge(_pad_rows(thresholds, block_n, value=0.0), _LANE)
-    bp = _pad_cols_edge(_pad_rows(table, block_n, value=1.0), _LANE)
-    # B pads to a sublane multiple with zero queries; their score columns
-    # are well-defined (score 0 against edge-padded thresholds) and are
-    # sliced off below.
-    qt = _pad_rows(qs.astype(jnp.float32), 8).T             # (d, Bp)
+    qp = _pad_rows(qs.astype(jnp.float32), 8)               # (Bp, d)
     r_lo, r_up, est = _us.bound_ranks_batched_kernel_call(
-        up, qt, tp, bp, m=m, tau_valid=tau, block_n=block_n)
-    return r_lo[:n, :B].T, r_up[:n, :B].T, est[:n, :B].T
+        users.astype(jnp.float32).T, qp, thresholds.T, table.T, m=m,
+        block_n=block_n)
+    return r_lo[:B], r_up[:B], est[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("m", "block_n"))
@@ -84,26 +87,23 @@ def bound_ranks_batched_pruned(users: jax.Array, qs: jax.Array,
                                ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Masked-grid batched step 1 (PR 4): like `bound_ranks_batched`, but
     the Pallas grid runs only over the user tiles named in `block_ids`
-    ((nk,) int32, one id per block_n-row tile) via a scalar-prefetch
-    block index map — skipped tiles are never read from HBM.
+    ((nk,) int32, one id per block_n-user tile) via a scalar-prefetch
+    block index map — skipped tiles are never read from HBM. The index is
+    read in place, as in `bound_ranks_batched`.
 
     Returns COMPACTED (r↓, r↑, est), each (B, nk·block_n) float32 in
     block-list order (tile j of the outputs is user tile block_ids[j]);
     the caller scatters back to user coordinates
-    (`core.pruning.scatter_select`). Tail-tile padding rows carry
-    well-defined junk exactly like the unpruned wrapper's — the scatter
-    drops them.
+    (`core.pruning.finish_compacted`). When the ragged last tile is kept,
+    its lanes past n read m + 1 — finite, and dropped by the caller's row
+    bound (`ridx < n`).
     """
-    tau = thresholds.shape[1]
-    up = _pad_rows(users.astype(jnp.float32), block_n)
-    tp = _pad_cols_edge(_pad_rows(thresholds, block_n, value=0.0), _LANE)
-    bp = _pad_cols_edge(_pad_rows(table, block_n, value=1.0), _LANE)
-    qt = _pad_rows(qs.astype(jnp.float32), 8).T             # (d, Bp)
     B = qs.shape[0]
+    qp = _pad_rows(qs.astype(jnp.float32), 8)               # (Bp, d)
     r_lo, r_up, est = _us.bound_ranks_batched_masked_kernel_call(
-        up, qt, tp, bp, block_ids.astype(jnp.int32), m=m, tau_valid=tau,
-        block_n=block_n)
-    return r_lo[:, :B].T, r_up[:, :B].T, est[:, :B].T
+        users.astype(jnp.float32).T, qp, thresholds.T, table.T,
+        block_ids.astype(jnp.int32), m=m, block_n=block_n)
+    return r_lo[:B], r_up[:B], est[:B]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n",))

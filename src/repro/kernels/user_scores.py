@@ -1,31 +1,39 @@
 """Fused bound-rank kernels — the query's O(nd) hot loop (§4.3 step 1).
 
 One pass over the user matrix produces (r↓, r↑, est) for a whole batch
-of B queries:
+of B queries. The f32 kernel reads the index as the chip stores it,
+users on lanes: `users.T` (d, n) and `thresholds.T`/`table.T` (τ, n) are
+views, not copies (ops.py):
 
     HBM                          VMEM (per grid step i)
-    U[i·Bn : (i+1)·Bn, :] ──►    (Bn, d) user tile        ─┐
-    Qᵀ                    ──►    (d, B) query block        ├─ MXU matmul
-    thresholds[i·Bn:…, :] ──►    (Bn, τ) ascending grid    │  (Bn, B) scores
-    table[i·Bn:…, :]      ──►    (Bn, τ) rank estimates   ─┘
+    Uᵀ[:, i·Bn : (i+1)·Bn] ──►   (d, Bn) user tile        ─┐
+    Q                      ──►   (B, d) query block        ├─ MXU matmul
+    thresholdsᵀ[:, i·Bn:…] ──►   (τ, Bn) ascending grid    │  (B, Bn) scores
+    tableᵀ[:, i·Bn:…]      ──►   (τ, Bn) rank estimates   ─┘
                                   VPU: count-bucketize + gather + lerp
-    r_lo/r_up/est[i·Bn:…] ◄──    three (Bn, B) outputs
+    r_lo/r_up/est[:, i·Bn:…] ◄── three (B, Bn) outputs, row j = query j
 
-The (n, B) score matrix never round-trips to HBM, and the dominant
+The (B, n) score matrix never round-trips to HBM, and the dominant
 n·(d + 2τ) HBM stream is read once per BATCH instead of once per query —
 the table-bandwidth amortization the batched engine API exists for. The
 extra cost is VPU work (B× compares on data already in VMEM). Block
-sizes: Bn = block_n users/step (multiple of 8 sublanes; τ lands on
-128-lane tiles after padding by ops.py). A single query is the B = 1
-case (ops.bound_ranks pads it to one sublane group).
+sizes: Bn = block_n users/step (a multiple of 128 lanes); τ and d are
+whole block dimensions, so neither pads. The grid is cdiv(n, Bn): the
+last tile may be ragged, and its lanes past n never reach an output
+(the full grid drops them; the masked grid writes m + 1 there). A
+single query is the B = 1 case (ops.bound_ranks pads it to one sublane
+group).
 
-The bucketize is branch-free: idx = Σ_j I[t_j ≤ s AND j < τ_valid], which
-equals searchsorted(side='right') for ascending thresholds; padded τ
-columns are masked via the `tau_valid` scalar so ops.py can pad τ to a
-lane multiple without changing semantics. Each query column bucketizes
-and reads the table as 2-D (Bn, τp) work (`_by_query`), and the table
-reads are one-hot select-sums (`_take`) — the forms Mosaic lowers for
-the TPU.
+The bucketize is branch-free: idx = Σ_j I[t_j ≤ s], which equals
+searchsorted(side='right') for ascending thresholds. Each query's
+(1, Bn) score row bucketizes against the (τ, Bn) tile with every
+reduction down the sublanes, and the table reads are one-hot
+select-sums — the forms Mosaic lowers for the TPU.
+
+The bf16 and int8 kernels keep the older orientation: (Bn, τp) tiles of
+a row-major copy padded by ops.py, τp a lane multiple with padded
+columns masked through `tau_valid`, each query column worked as 2-D
+(Bn, τp) work (`_by_query`, `_take`).
 """
 from __future__ import annotations
 
@@ -41,13 +49,14 @@ from repro.core.types import EPS_BF16, _I8_TRANSFORM_PAD, dot_precision, \
     round_bf16
 from repro.kernels import interpret_mode
 
-# QUANTIZED-STORAGE VARIANTS (PR 5): the same grid and the same per-tile
-# structure, but the HBM operands are the storage-tier arrays — bf16
-# rows, or int8 codes plus (block_n, 1) per-row scale/offset vectors that
-# ride the same tile index maps. The DMA moves the quantized bytes (the
-# ~2×/4× bandwidth win); dequantization is VPU work on VMEM-resident
-# tiles (the "int8-input / f32-accumulate" shape: the MXU matmul runs on
-# in-register f32 casts of the int8 user tile). Quantization error is
+# QUANTIZED-STORAGE VARIANTS: the same per-tile work on row-major
+# (Bn, τp) tiles (module docstring); the HBM operands are the
+# storage-tier arrays — bf16 rows, or int8 codes plus (block_n, 1)
+# per-row scale/offset vectors that ride the same tile index maps. The
+# DMA moves the quantized bytes (the ~2×/4× bandwidth win);
+# dequantization is VPU work on VMEM-resident tiles (the "int8-input /
+# f32-accumulate" shape: the MXU matmul runs on in-register f32 casts of
+# the int8 user tile). Quantization error is
 # folded into the outputs — r↓ rounds down, r↑ rounds up, mirroring the
 # dense `query._lookup_bounds_{bf16,int8}` certification — so Lemma-1
 # selection over kernel outputs stays sound at every spec.
@@ -75,71 +84,79 @@ def _by_query(fn, *xs):
     return outs
 
 
-def _take(a, at):
+def _take(a, at, axis: int = 1):
     """Row-wise gather a[r, c_r] as a one-hot select-sum: `at` is the
     (Bn, τp) mask col == c, with exactly one true entry per row, so the
     sum adds one value to zeros — bit-identical to take_along_axis (which
-    Mosaic cannot lower for a (Bn, 1) index into a (Bn, τp) table)."""
-    return jnp.sum(jnp.where(at, a, 0.0), axis=1, keepdims=True)
+    Mosaic cannot lower for a (Bn, 1) index into a (Bn, τp) table).
+    axis=0 gathers per column of a (τ, Bn) tile instead."""
+    return jnp.sum(jnp.where(at, a, 0.0), axis=axis, keepdims=True)
 
 
-def _bound_rank_batched_kernel(u_ref, qt_ref, thr_ref, tab_ref, rlo_ref,
-                               rup_ref, est_ref, *, m: int, tau_valid: int,
-                               precision):
-    """All B queries against one VMEM-resident user/threshold/table tile
-    (see module docstring)."""
-    u = u_ref[...].astype(jnp.float32)                    # (Bn, d)
-    qt = qt_ref[...].astype(jnp.float32)                  # (d, B)
-    thr = thr_ref[...]                                    # (Bn, τp)
-    tab = tab_ref[...]                                    # (Bn, τp)
-    taup = thr.shape[1]
+def _bound_rank_batched_kernel(ut_ref, q_ref, thr_ref, tab_ref, rlo_ref,
+                               rup_ref, est_ref, *, m: int, precision,
+                               lane_ok=None):
+    """All B queries against one VMEM-resident user/threshold/table tile,
+    users on lanes (see module docstring).
+
+    Every reduction over τ runs down the sublanes of the (τ, Bn) tile, and
+    query j's results are row j of each (B, Bn) output block. `lane_ok`
+    ((1, Bn) bool or None) marks the lanes that hold users: the others
+    read whatever lies past n and are written as m + 1, a finite rank no
+    selection picks.
+    """
+    ut = ut_ref[...].astype(jnp.float32)                  # (d, Bn)
+    q = q_ref[...].astype(jnp.float32)                    # (B, d)
+    thr = thr_ref[...]                                    # (τ, Bn)
+    tab = tab_ref[...]                                    # (τ, Bn)
+    tau = thr.shape[0]
+    m1 = float(m + 1)
 
     score = jax.lax.dot_general(
-        u, qt, (((1,), (0,)), ((), ())),
+        q, ut, (((1,), (0,)), ((), ())),
         precision=precision,
-        preferred_element_type=jnp.float32)               # (Bn, B) one matmul
+        preferred_element_type=jnp.float32)               # (B, Bn) one matmul
 
-    col = jax.lax.broadcasted_iota(jnp.int32, thr.shape, 1)
-    valid = col < tau_valid                               # (Bn, τp)
-
-    def lookup(s):                                        # s: (Bn, 1)
-        # every query column bucketizes against the SAME resident tile
-        idx = jnp.sum(((thr <= s) & valid).astype(jnp.int32), axis=1,
-                      keepdims=True)                      # ∈ [0, τ]
-        at_up = col == jnp.clip(idx - 1, 0, taup - 1)
-        at_lo = col == jnp.clip(idx, 0, tau_valid - 1)
-        return (idx, _take(tab, at_up), _take(tab, at_lo),
-                _take(thr, at_up), _take(thr, at_lo))
-
-    idx, t_up, t_lo, lo_thr, hi_thr = _by_query(lookup, score)
-    r_up = jnp.where(idx == 0, float(m + 1), t_up)
-    r_lo = jnp.where(idx == tau_valid, 1.0, t_lo)
-
-    span = jnp.maximum(hi_thr - lo_thr, 1e-12)
-    frac = jnp.clip((score - lo_thr) / span, 0.0, 1.0)
-    interior = (idx > 0) & (idx < tau_valid)
-    est_in = r_up + (r_lo - r_up) * frac
-    # margin-decayed out-of-range estimate (matches ref_bound_ranks)
-    t_lo_edge = thr[:, :1]                                # (Bn, 1)
-    t_hi_edge = thr[:, tau_valid - 1:tau_valid]
+    row = jax.lax.broadcasted_iota(jnp.int32, thr.shape, 0)
+    t_lo_edge = thr[:1]                                   # (1, Bn)
+    t_hi_edge = thr[tau - 1:]
     rng = jnp.maximum(t_hi_edge - t_lo_edge, 1e-12)
-    m_above = jnp.maximum(score - t_hi_edge, 0.0) / rng
-    m_below = jnp.maximum(t_lo_edge - score, 0.0) / rng
-    est_above = 1.0 + (r_up - 1.0) / (1.0 + tau_valid * m_above)
-    est_below = float(m + 1) - (float(m + 1) - r_lo) * jnp.exp(
-        -tau_valid * m_below)
-    est = jnp.where(interior, est_in,
-                    jnp.where(idx == tau_valid, est_above, est_below))
 
-    rlo_ref[...] = r_lo
-    rup_ref[...] = r_up
-    # sub-unit margin tie-break (matches ref_bound_ranks)
-    est_ref[...] = jnp.clip(est, r_lo, r_up) - 0.5 * m_above / (1.0 + m_above)
+    def put(ref, j, v):
+        ref[j:j + 1, :] = v if lane_ok is None else jnp.where(lane_ok, v, m1)
+
+    for j in range(score.shape[0]):    # B is static and small: unrolled
+        s = score[j:j + 1]                                # (1, Bn)
+        # every query bucketizes against the SAME resident tile
+        idx = jnp.sum((thr <= s).astype(jnp.int32), axis=0,
+                      keepdims=True)                      # ∈ [0, τ]
+        at_up = row == jnp.clip(idx - 1, 0, tau - 1)
+        at_lo = row == jnp.clip(idx, 0, tau - 1)
+        r_up = jnp.where(idx == 0, m1, _take(tab, at_up, axis=0))
+        r_lo = jnp.where(idx == tau, 1.0, _take(tab, at_lo, axis=0))
+        lo_thr, hi_thr = _take(thr, at_up, axis=0), _take(thr, at_lo, axis=0)
+
+        span = jnp.maximum(hi_thr - lo_thr, 1e-12)
+        frac = jnp.clip((s - lo_thr) / span, 0.0, 1.0)
+        interior = (idx > 0) & (idx < tau)
+        est_in = r_up + (r_lo - r_up) * frac
+        # margin-decayed out-of-range estimate (matches ref_bound_ranks)
+        m_above = jnp.maximum(s - t_hi_edge, 0.0) / rng
+        m_below = jnp.maximum(t_lo_edge - s, 0.0) / rng
+        est_above = 1.0 + (r_up - 1.0) / (1.0 + tau * m_above)
+        est_below = m1 - (m1 - r_lo) * jnp.exp(-tau * m_below)
+        est = jnp.where(interior, est_in,
+                        jnp.where(idx == tau, est_above, est_below))
+        put(rlo_ref, j, r_lo)
+        put(rup_ref, j, r_up)
+        # sub-unit margin tie-break (matches ref_bound_ranks)
+        put(est_ref, j,
+            jnp.clip(est, r_lo, r_up) - 0.5 * m_above / (1.0 + m_above))
 
 
 def bound_ranks_batched_masked_kernel_call(
-        users: jax.Array, qt: jax.Array, thresholds: jax.Array,
-        table: jax.Array, block_ids: jax.Array, *, m: int, tau_valid: int,
+        users_t: jax.Array, qs: jax.Array, thresholds_t: jax.Array,
+        table_t: jax.Array, block_ids: jax.Array, *, m: int,
         block_n: int = 256
         ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Masked-grid twin of `bound_ranks_batched_kernel_call` (PR 4): the
@@ -150,86 +167,88 @@ def bound_ranks_batched_masked_kernel_call(
     operand (`pltpu.PrefetchScalarGridSpec`), so the DMA engine fetches
     exactly the surviving tiles and the n·(d + 2τ) HBM stream shrinks to
     the kept fraction. Outputs are COMPACTED: grid step i writes tile i
-    of three (nk·block_n, B) arrays (the caller scatters them back to
+    of three (B, nk·block_n) arrays (the caller scatters them back to
     user coordinates — writing through the same index map would leave
     skipped tiles uninitialized).
 
     Per-tile math is `_bound_rank_batched_kernel` verbatim — a kept
-    tile's (block_n, d) × (d, B) matmul sees the identical operand tile
-    as the full scan, so compacted results are bit-identical to the
-    corresponding rows of the unpruned kernel.
+    tile's matmul sees the identical operand tile as the full scan, so
+    compacted results are bit-identical to the corresponding columns of
+    the unpruned kernel. The one difference is the ragged last tile: its
+    lanes past n are written as m + 1 here (the full grid drops them), so
+    the compacted arrays hold only finite values.
     """
-    n, d = users.shape
-    taup = thresholds.shape[1]
-    B = qt.shape[1]
+    d, n = users_t.shape
+    tau = thresholds_t.shape[0]
+    B = qs.shape[0]
     nk = block_ids.shape[0]
     kern = functools.partial(_bound_rank_batched_kernel, m=m,
-                             tau_valid=tau_valid,
                              precision=dot_precision())
 
     def tile(i, ids):
-        return (ids[i], 0)
+        return (0, ids[i])
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nk,),
         in_specs=[
-            pl.BlockSpec((block_n, d), tile),               # U tile (gathered)
-            pl.BlockSpec((d, B), lambda i, ids: (0, 0)),    # Qᵀ (replicated)
-            pl.BlockSpec((block_n, taup), tile),
-            pl.BlockSpec((block_n, taup), tile),
+            pl.BlockSpec((d, block_n), tile),               # Uᵀ tile
+            pl.BlockSpec((B, d), lambda i, ids: (0, 0)),    # Q (replicated)
+            pl.BlockSpec((tau, block_n), tile),
+            pl.BlockSpec((tau, block_n), tile),
         ],
-        out_specs=[pl.BlockSpec((block_n, B), lambda i, ids: (i, 0))] * 3,
+        out_specs=[pl.BlockSpec((B, block_n), lambda i, ids: (0, i))] * 3,
     )
 
-    def wrapped(ids_ref, u_ref, qt_ref, thr_ref, tab_ref, rlo_ref, rup_ref,
-                est_ref):
-        # the prefetched id array steers the index maps only; the tile
-        # body is the stock batched kernel
-        kern(u_ref, qt_ref, thr_ref, tab_ref, rlo_ref, rup_ref, est_ref)
+    def wrapped(ids_ref, *refs):
+        # the prefetched id array steers the index maps and names the
+        # tile's first user; the tile body is the stock batched kernel
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, block_n), 1)
+        first = ids_ref[pl.program_id(0)] * block_n
+        kern(*refs, lane_ok=first + lane < n)
 
-    out_shape = [jax.ShapeDtypeStruct((nk * block_n, B), jnp.float32)] * 3
+    out_shape = [jax.ShapeDtypeStruct((B, nk * block_n), jnp.float32)] * 3
     return pl.pallas_call(
         wrapped,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret_mode(),
-    )(block_ids, users, qt, thresholds, table)
+    )(block_ids, users_t, qs, thresholds_t, table_t)
 
 
-def bound_ranks_batched_kernel_call(users: jax.Array, qt: jax.Array,
-                                    thresholds: jax.Array, table: jax.Array,
-                                    *, m: int, tau_valid: int,
+def bound_ranks_batched_kernel_call(users_t: jax.Array, qs: jax.Array,
+                                    thresholds_t: jax.Array,
+                                    table_t: jax.Array, *, m: int,
                                     block_n: int = 256
                                     ) -> tuple[jax.Array, jax.Array,
                                                jax.Array]:
-    """Raw batched pallas_call; inputs pre-padded (see ops.bound_ranks_batched).
-
-    users (n, d) [n % block_n == 0], qt (d, B) [B a sublane multiple],
-    thresholds/table (n, τp) f32. Returns three (n, B) float32 arrays.
+    """Raw batched pallas_call over the index as it is stored: users_t
+    (d, n), thresholds_t/table_t (τ, n) f32 (users on lanes), qs (B, d)
+    [B a sublane multiple]. The grid is cdiv(n, block_n); the last tile
+    may be ragged, and its lanes past n are never written. Returns three
+    (B, n) float32 arrays (query-major).
     """
-    n, d = users.shape
-    taup = thresholds.shape[1]
-    B = qt.shape[1]
-    nb = n // block_n
+    d, n = users_t.shape
+    tau = thresholds_t.shape[0]
+    B = qs.shape[0]
     kern = functools.partial(_bound_rank_batched_kernel, m=m,
-                             tau_valid=tau_valid,
                              precision=dot_precision())
-    out_shape = [jax.ShapeDtypeStruct((n, B), jnp.float32)] * 3
-    out_spec = pl.BlockSpec((block_n, B), lambda i: (i, 0))
+    tile = lambda i: (0, i)
+    out_shape = [jax.ShapeDtypeStruct((B, n), jnp.float32)] * 3
+    out_spec = pl.BlockSpec((B, block_n), tile)
     return pl.pallas_call(
         kern,
-        grid=(nb,),
+        grid=(pl.cdiv(n, block_n),),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i: (i, 0)),   # U tile
-            pl.BlockSpec((d, B), lambda i: (0, 0)),         # Qᵀ (replicated)
-            pl.BlockSpec((block_n, taup), lambda i: (i, 0)),
-            pl.BlockSpec((block_n, taup), lambda i: (i, 0)),
+            pl.BlockSpec((d, block_n), tile),               # Uᵀ tile
+            pl.BlockSpec((B, d), lambda i: (0, 0)),         # Q (replicated)
+            pl.BlockSpec((tau, block_n), tile),
+            pl.BlockSpec((tau, block_n), tile),
         ],
         out_specs=[out_spec, out_spec, out_spec],
         out_shape=out_shape,
         interpret=interpret_mode(),
-    )(users, qt, thresholds, table)
+    )(users_t, qs, thresholds_t, table_t)
 
 
 def _est_tail(score, idx_hi, thr_up, thr_lo, edge_lo, edge_hi, r_lo, r_up,

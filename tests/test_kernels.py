@@ -26,6 +26,8 @@ def _table_for(users, items, tau, key=0):
     (300, 200, 100),       # paper-ish d/τ, ragged n and τ (padding path)
     (1024, 64, 500),       # paper τ
     (64, 32, 7),           # tiny, heavy padding
+    (257, 200, 37),        # one user into a ragged last tile, τ % 8 ≠ 0
+    (1000, 64, 500),       # ragged last tile at the paper's τ
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_bound_ranks_kernel_vs_ref(n, d, tau, dtype):
@@ -46,6 +48,57 @@ def test_bound_ranks_kernel_vs_ref(n, d, tau, dtype):
                                    rtol=2e-2 if dtype == jnp.bfloat16 else 1e-5,
                                    atol=2.0 if dtype == jnp.bfloat16 else atol32,
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("n,tau", [(257, 37), (1000, 500)])
+@pytest.mark.parametrize("B", [1, 16])
+def test_bound_ranks_batched_vs_ref(n, tau, B):
+    """Every query row of the batched full grid matches the oracle where
+    n leaves a ragged last tile and τ is no multiple of 8: the grid's
+    last block stops at n, and the outputs are (B, n)."""
+    users, items = make_problem(jax.random.PRNGKey(n + B), n, 300, 32)
+    rt = _table_for(users, items, tau)
+    qs = items[3:3 + B]
+    got = ops.bound_ranks_batched(users, qs, rt.thresholds, rt.table,
+                                  m=int(rt.m))
+    for g in got:
+        assert g.shape == (B, n)
+    for j in range(B):
+        want = ref.ref_bound_ranks(users, qs[j], rt.thresholds, rt.table,
+                                   int(rt.m))
+        # est's wider band: see test_bound_ranks_kernel_vs_ref
+        for g, w, name, atol in zip(got, want, ("r_lo", "r_up", "est"),
+                                    (1e-4, 1e-4, 1e-3)):
+            np.testing.assert_allclose(np.asarray(g[j]), np.asarray(w),
+                                       rtol=1e-5, atol=atol,
+                                       err_msg=f"{name} query {j}")
+
+
+@pytest.mark.parametrize("B", [1, 16])
+def test_masked_grid_ragged_tail_matches_full_grid(B):
+    """The masked grid with the ragged last tile in its block list: each
+    compacted tile equals the full grid's columns of that tile bit for
+    bit, and the lanes past n read m + 1, so no non-finite value reaches
+    the selection."""
+    n, tau, bn = 1000, 37, 256
+    users, items = make_problem(jax.random.PRNGKey(11), n, 300, 32)
+    rt = _table_for(users, items, tau)
+    m = int(rt.m)
+    qs = items[5:5 + B]
+    ids = jnp.asarray([3, 0, 3], jnp.int32)     # tail first, repeated
+    full = ops.bound_ranks_batched(users, qs, rt.thresholds, rt.table, m=m,
+                                   block_n=bn)
+    comp = ops.bound_ranks_batched_pruned(users, qs, rt.thresholds,
+                                          rt.table, ids, m=m, block_n=bn)
+    for f, c in zip(full, comp):
+        f, c = np.asarray(f), np.asarray(c)
+        assert c.shape == (B, 3 * bn) and np.all(np.isfinite(c))
+        for j, b in enumerate(np.asarray(ids)):
+            cols = np.arange(b * bn, min((b + 1) * bn, n))
+            tile = c[:, j * bn:(j + 1) * bn]
+            np.testing.assert_array_equal(_bits(tile[:, :cols.size]),
+                                          _bits(f[:, cols]))
+            assert np.all(tile[:, cols.size:] == m + 1)
 
 
 def test_bound_ranks_matches_core_lookup():

@@ -136,9 +136,11 @@ def test_rhat_bounds_true_Rupk(problem, regimes):
     assert np.all(np.asarray(r_hat) >= true_up - 1e-6)
 
 
-def test_tail_block_summary():
+@pytest.mark.parametrize("inner", INNERS)
+def test_tail_block_summary(inner):
     """n not a multiple of block_size: the partial tail block's rows
-    count is exact and parity still holds."""
+    count is exact and parity still holds, on the masked-grid kernel's
+    ragged last tile too."""
     users, items = clustered_problem(jax.random.PRNGKey(3), n=1000, m=256)
     rt = build_rank_table(users, items, CFG_COARSE, jax.random.PRNGKey(1))
     summ = PR.build_block_summary(users, rt, block_size=BS)
@@ -146,7 +148,7 @@ def test_tail_block_summary():
     assert rows.sum() == 1000 and rows[-1] == 1000 - (1000 // BS) * BS
     ref = ReverseKRanksEngine(users=users, rank_table=rt,
                               config=CFG_COARSE)
-    eng = pruned_engine(users, rt, CFG_COARSE, "dense",
+    eng = pruned_engine(users, rt, CFG_COARSE, inner,
                         max_union_frac=1.1)
     qs = off_grid_queries(items, 4)
     assert_selected_parity(eng.query_batch(qs, k=K, c=1.0),

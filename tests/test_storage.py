@@ -387,10 +387,11 @@ def test_interpret_env_override(monkeypatch):
     from repro.kernels import user_scores as us
 
     def traced_interpret():
-        args = (jnp.zeros((256, 8)), jnp.zeros((8, 8)),
-                jnp.zeros((256, 128)), jnp.ones((256, 128)))
+        # the index as stored: users (d, n), thresholds/table (τ, n)
+        args = (jnp.zeros((8, 256)), jnp.zeros((8, 8)),
+                jnp.zeros((100, 256)), jnp.ones((100, 256)))
         jaxpr = jax.make_jaxpr(functools.partial(
-            us.bound_ranks_batched_kernel_call, m=10, tau_valid=100))(*args)
+            us.bound_ranks_batched_kernel_call, m=10))(*args)
         calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
         assert len(calls) == 1
         return bool(calls[0].params["interpret"])

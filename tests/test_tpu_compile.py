@@ -13,13 +13,14 @@ import: only one process may hold the TPU library, and a test file that
 loaded it at collection time would break every other pytest worker.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.configs.paper_engine import DEFAULT_TABLE, NETFLIX
+from repro.configs.paper_engine import DEFAULT_TABLE, MOVIELENS, NETFLIX
 from repro.core import elastic
 from repro.core import rank_table as rt_mod
 from repro.core.pruning import DEFAULT_BLOCK
@@ -116,6 +117,49 @@ def test_masked_grid_kernel_compiles(spec, one_chip, compiled_kernels):
             m=M, block_n=DEFAULT_BLOCK)
     c = _compile(fn, users, qs, rt, ids)
     assert _has_kernel(c)
+
+
+_ENTRY_OK = {"parameter", "bitcast", "custom-call", "tuple",
+             "get-tuple-element"}
+
+
+def _index_sized_ops(hlo: str, n: int) -> list[str]:
+    """Instructions of the entry computation, other than parameters,
+    bitcasts and the kernel, whose shapes hold an axis of n or more: a
+    copy, pad, transpose or fusion of an index-sized array."""
+    entry = hlo[hlo.index("\nENTRY"):]
+    entry = entry[:entry.index("\n}")]
+    found = []
+    for line in entry.splitlines()[1:]:
+        op = re.search(r"=\s.*?\s([a-z][\w-]*)\(", line)
+        dims = [int(x) for x in re.findall(r"\d+", " ".join(
+            re.findall(r"\[([\d,]*)\]", line.split(", metadata")[0])))]
+        if op and op.group(1) not in _ENTRY_OK and max(dims, default=0) >= n:
+            found.append(line.strip()[:160])
+    return found
+
+
+@pytest.mark.parametrize("grid", ["full", "masked"])
+def test_f32_scan_reads_index_in_place(grid, one_chip, compiled_kernels):
+    """The f32 scan takes the resident index as the chip lays it out: at
+    Netflix's shapes (full grid) and MovieLens's (masked grid, 174 kept
+    tiles) the program applies nothing but bitcasts to the index
+    operands, and its temporaries stay far below one index copy."""
+    n, m = (N, M) if grid == "full" else (MOVIELENS.n_users,
+                                          MOVIELENS.n_items)
+    users, rt = _stored("f32", n, one_chip)
+    qs = jax.ShapeDtypeStruct((B, D), jnp.float32, sharding=one_chip)
+    if grid == "full":
+        c = _compile(lambda u, q, t: ops.bound_ranks_batched(
+            u, q, t.thresholds, t.table, m=m), users, qs, rt)
+    else:
+        ids = jax.ShapeDtypeStruct((174,), jnp.int32, sharding=one_chip)
+        c = _compile(lambda u, q, t, i: ops.bound_ranks_batched_pruned(
+            u, q, t.thresholds, t.table, i, m=m, block_n=DEFAULT_BLOCK),
+            users, qs, rt, ids)
+    assert _has_kernel(c)
+    assert _index_sized_ops(c.as_text(), n) == []
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 def test_elastic_fused_program_compiles(one_chip, compiled_kernels):
